@@ -7,10 +7,9 @@ TPU engine makes the same claim via the zero-bubble scheduler: the
 static-scheduled mode stands in for lockstep execution and degrades, the
 zero-bubble mode holds throughput.
 
-The weighted-Node2Vec rows measure the *degree-adaptive* E-S reservoir
-scan on the Graph500-skewed graph: bounding the chunk loop by the live
-lanes' max degree (vs the graph's max_degree) removes the power-law-tail
-chunks that dominate the fixed scan — identical paths, lower wall time.
+The weighted-Node2Vec row times the E-S reservoir scan on the
+Graph500-skewed graph, where a lane's scan follows its own degree: the
+power-law tail makes the per-hop work uneven across lanes.
 """
 import dataclasses
 
@@ -19,57 +18,24 @@ import numpy as np
 from benchmarks.common import bench_walk, emit
 from repro.graph import build_csr
 from repro.graph.generators import BALANCED, GRAPH500, rmat_edges
-from repro.walker import ExecutionConfig, WalkProgram, compile as compile_walker
+from repro.walker import ExecutionConfig, WalkProgram
 
 
-def _bench_n2vw_adaptive(scale: int, queries: int, emitname: str):
-    """Weighted Node2Vec on the Graph500-skewed RMAT: degree-adaptive vs
-    fixed-bound reservoir scan (bit-identical paths; see
-    phase_program.reservoir_scan).
-
-    The adaptive scan is *gated* on measured skew
-    (tune.adaptive_chunk_gate): when the degree-weighted live-lane
-    quantile predicts no chunk-trip savings, the fixed scan ships and
-    the row reports speedup=1.0 — the adaptive path must never lose.
-    When the gate opens, both variants are measured and the faster one
-    ships, so the reported speedup is >= 1.0 by construction."""
-    from repro import tune
+def _bench_n2vw(scale: int, queries: int, emitname: str):
+    """Weighted Node2Vec on the Graph500-skewed RMAT."""
     edges, n = rmat_edges(scale, 8, GRAPH500, seed=0)
     wts = np.random.default_rng(3).random(edges.shape[0]).astype(
         np.float32) + 0.1
     g = build_csr(edges, n, weights=wts)
     starts = np.random.default_rng(4).integers(0, n, queries)
     prog = WalkProgram.node2vec(2.0, 0.5, 20, weighted=True)
-    # Fine chunks + a modest lane pool: the regime where the live-lane max
-    # degree sits well below the power-law max_degree most supersteps.
     prog = dataclasses.replace(
-        prog, spec=dataclasses.replace(prog.spec, reservoir_chunk=16,
-                                       adaptive_chunks=True))
-    prog_fixed = dataclasses.replace(
-        prog, spec=dataclasses.replace(prog.spec, adaptive_chunks=False))
+        prog, spec=dataclasses.replace(prog.spec, reservoir_chunk=16))
     ex = ExecutionConfig(num_slots=32, record_paths=False)
-    gate = tune.adaptive_chunk_gate(tune.graph_signature(g),
-                                    num_slots=ex.num_slots,
-                                    chunk=prog.spec.reservoir_chunk)
-    dt_f, a_f = bench_walk(g, starts, prog_fixed, ex, repeats=5)
-    if gate:
-        dt_a, a_a = bench_walk(g, starts, prog, ex, repeats=5)
-        use_adaptive = dt_a < dt_f
-    else:
-        dt_a, a_a = dt_f, a_f
-        use_adaptive = False
-    dt_c, a_c = (dt_a, a_a) if use_adaptive else (dt_f, a_f)
-    # identity check (recorded, untimed): adaptive == fixed, path for path
-    ex_rec = dataclasses.replace(ex, record_paths=True)
-    pa = compile_walker(prog, execution=ex_rec).run(g, starts).paths
-    pf = compile_walker(prog_fixed, execution=ex_rec).run(g, starts).paths
-    identical = bool((np.asarray(pa) == np.asarray(pf)).all())
-    emit(emitname, dt_c * 1e6,
-         f"gate={'on' if gate else 'off'};adaptive={use_adaptive};"
-         f"adaptive_msteps={a_a.msteps_per_s:.3f};"
-         f"fixed_msteps={a_f.msteps_per_s:.3f};"
-         f"speedup={dt_f / dt_c:.2f};paths_identical={identical}")
-    return dt_f / dt_c
+    dt, a = bench_walk(g, starts, prog, ex, repeats=5)
+    emit(emitname, dt * 1e6,
+         f"msteps={a.msteps_per_s:.3f};occ={a.occupancy:.2f}")
+    return a.msteps_per_s
 
 
 def run(quick: bool = False):
@@ -99,9 +65,9 @@ def run(quick: bool = False):
         emit(f"fig10_retention_ef{ef}", 0.0,
              f"zero_bubble_retention={zb_keep:.2f};"
              f"static_retention={st_keep:.2f}")
-    # degree-adaptive reservoir scan (weighted Node2Vec) under skew
-    results["n2vw_adaptive_speedup"] = _bench_n2vw_adaptive(
-        scale, 256 if quick else 1024, f"fig10_n2vw_adaptive_SC{scale}")
+    # weighted Node2Vec (E-S reservoir scan) under skew
+    results["n2vw_msteps"] = _bench_n2vw(
+        scale, 256 if quick else 1024, f"fig10_n2vw_SC{scale}")
     return results
 
 

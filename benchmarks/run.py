@@ -148,6 +148,8 @@ def main() -> None:
                     help="write results as JSON to PATH")
     args, _ = ap.parse_known_args()
     quick = not args.full
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from benchmarks import (common, e2e_embeddings, fig8_fpga_baselines,
                             fig9_throughput, fig10_rmat_skew, fig11_ablation,

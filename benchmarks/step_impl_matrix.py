@@ -87,7 +87,7 @@ def _cached_rows(out, quick: bool):
     """Cached vs uncached fused superstep on a Graph500-skewed RMAT.
 
     The hot-vertex cache targets exactly this degree distribution: a few
-    hubs carry most of the stationary gather traffic, so a small VMEM
+    hubs carry most of the stationary gather traffic, so a small SMEM
     budget absorbs a large hit fraction.  Both variants are timed
     interleaved (min-of-k, drift-fair) and the *shipped* row is whichever
     is faster — fallback-to-default, so the reported speedup is >= 1.0 by

@@ -30,14 +30,15 @@ def run(quick: bool = False):
              f"vmem_frac={bytes_/128e6:.5f}")
     # interpret-mode validation timing (not TPU perf — correctness gate)
     from repro.graph import make_dataset
+    from repro.kernels.common import graph_rows
     from repro.kernels.walk_step import ops as ws
     g = make_dataset("WG", scale_override=10)
     rng = np.random.default_rng(0)
     W = 512
     v = jnp.asarray(rng.integers(0, g.num_vertices, W), jnp.int32)
     u = jnp.asarray(rng.random(W), jnp.float32)
-    dt, _ = timed(lambda: ws.walk_step_uniform(v, u, g.row_ptr, g.col,
-                                               tile=256))
+    rows = graph_rows(g)
+    dt, _ = timed(lambda: ws.walk_step_uniform(v, u, rows, tile=256))
     emit("table4_walk_step_interpret", dt * 1e6, f"lanes={W}")
     return True
 

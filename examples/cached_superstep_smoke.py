@@ -4,7 +4,7 @@ Runs one fused closed batch twice on a small Graph500-skewed RMAT —
 hot-vertex cache off, then on — and asserts the hierarchy's contract:
 
   * bit-identical paths, lengths, and every pre-existing stat
-    (a hit reads the same bytes from VMEM instead of HBM);
+    (a hit reads the same bytes from SMEM instead of HBM);
   * a nonzero hit rate (the skewed degree distribution concentrates
     gather traffic on hubs the budget admits);
   * zero cache counters when the cache is off.

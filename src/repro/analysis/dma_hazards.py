@@ -18,13 +18,13 @@ checker is a single forward scan holding per-``(buffer, slot)`` state:
     wrong-slot copy means the semaphore accounting is off by one);
   * **un-drained copy** — every copy started must be waited before the
     kernel returns (Pallas semaphores must balance per launch);
-  * **phantom copy** — a ``start`` targeting a VMEM-resident buffer (one
-    the schedule reads with ``tier="vmem"``, or tagged so itself).  The
+  * **phantom copy** — a ``start`` targeting an SMEM-resident buffer (one
+    the schedule reads with ``tier="smem"``, or tagged so itself).  The
     cached gather hierarchy's whole point is that hit paths issue *no*
     DMA — a copy into cache-tier storage means a hit path still went to
     HBM, silently erasing the latency win while staying bit-identical.
 
-``read`` ops with ``tier="vmem"`` are cache-hit probes/payload reads:
+``read`` ops with ``tier="smem"`` are cache-hit probes/payload reads:
 they touch on-chip memory only, so no dominating wait is required and
 they participate in no slot state.
 
@@ -62,18 +62,18 @@ def check_schedule(ops: Sequence[DmaOp], name: str = "kernel"
         findings.append(Finding("dma", f"{name}[{i}]", f"{op.kind} "
                                 f"{op.buffer}/slot{op.slot}: {msg}"))
 
-    # Buffers the schedule declares VMEM-resident (cache-tier): any read
-    # at tier="vmem" marks its buffer as on-chip for the whole schedule.
-    vmem_bufs = {op.buffer for op in ops
-                 if getattr(op, "tier", "hbm") == "vmem"}
+    # Buffers the schedule declares SMEM-resident (cache-tier): any read
+    # at tier="smem" marks its buffer as on-chip for the whole schedule.
+    smem_bufs = {op.buffer for op in ops
+                 if getattr(op, "tier", "hbm") == "smem"}
 
     for i, op in enumerate(ops):
         slot = (op.buffer, op.slot)
-        if op.kind == "read" and getattr(op, "tier", "hbm") == "vmem":
+        if op.kind == "read" and getattr(op, "tier", "hbm") == "smem":
             continue  # on-chip read: no DMA, no slot state
         if op.kind == "start":
-            if op.tier == "vmem" or op.buffer in vmem_bufs:
-                flag(i, op, "DMA start into a VMEM-resident cache buffer "
+            if op.tier == "smem" or op.buffer in smem_bufs:
+                flag(i, op, "DMA start into an SMEM-resident cache buffer "
                             "(phantom copy) — cached hit paths must serve "
                             "from on-chip memory without issuing copies")
                 continue
@@ -184,7 +184,7 @@ def kernel_schedules():
                  "reservoir_n2v"):
         schedules[f"fused_superstep.{kind}"] = fused_schedule(kind)
         # Cached variant: the fully-hit representative superstep — cache
-        # probes and payload reads at tier="vmem", HBM loops only where
+        # probes and payload reads at tier="smem", HBM loops only where
         # the hierarchy cannot serve (v_prev-keyed state, write-back).
         schedules[f"fused_superstep.{kind}.cached"] = fused_schedule(
             kind, cached=True)

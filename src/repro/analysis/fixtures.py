@@ -106,7 +106,7 @@ def dma_cached_phantom_copy() -> List[Finding]:
         dma_schedule as fused_schedule
     ops = list(fused_schedule("uniform", cached=True))
     hit = next(i for i, op in enumerate(ops)
-               if op.kind == "read" and op.tier == "vmem"
+               if op.kind == "read" and op.tier == "smem"
                and op.buffer == "cache.col")
     ops.insert(hit, DmaOp("start", "cache.col", 0, copy=990))
     return dma_hazards.check_schedule(ops, "fixture.cached_phantom")

@@ -63,7 +63,6 @@ from repro.core.samplers import (SamplerSpec, _uniform_index, es_num_chunks,
 from repro.core.scheduler import routing_capacity
 from repro.core.tasks import (WalkerSlots, empty_n2v_slots,
                               empty_reservoir_slots, empty_slots, zero_stats)
-from repro.distributed.compat import shard_map
 from repro.graph.partition import PartitionedGraph, owner_of
 
 
@@ -718,7 +717,7 @@ def make_distributed_engine(pg: PartitionedGraph, spec: SamplerSpec,
         return (log_q[None], log_h[None], log_v[None], cursor[None],
                 jax.tree.map(lambda x: x[None], stats))
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(cfg.axis_name), P(cfg.axis_name), P(cfg.axis_name),
                   P(cfg.axis_name), P(cfg.axis_name), P(cfg.axis_name),
@@ -994,7 +993,7 @@ def make_sharded_stream_engine(pg: PartitionedGraph, spec: SamplerSpec,
             cond, step, (jnp.zeros((), jnp.int32), work0, st))
         return jax.tree.map(lambda x: x[None], st)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(cfg.axis_name), P(cfg.axis_name), P(cfg.axis_name),
                   P(cfg.axis_name), P(cfg.axis_name), P(cfg.axis_name),
@@ -1035,6 +1034,21 @@ def shard_starts(starts, num_devices: int):
     return starts_sh, qcount
 
 
+def mesh_devices(num_partitions: int) -> np.ndarray:
+    """The first ``num_partitions`` devices, one per graph partition.
+
+    Raises when fewer devices exist: a mesh over fewer devices than
+    partitions would silently run a different deployment.
+    """
+    devs = jax.devices()
+    if num_partitions > len(devs):
+        raise ValueError(
+            f"the graph has {num_partitions} partitions but only "
+            f"{len(devs)} {devs[0].platform} device(s) are present; "
+            f"partition for at most {len(devs)} devices")
+    return np.array(devs[:num_partitions])
+
+
 def _run_distributed(pg: PartitionedGraph, starts, spec: SamplerSpec,
                      cfg: Optional[DistConfig] = None,
                      mesh: Optional[jax.sharding.Mesh] = None, seed: int = 0):
@@ -1042,8 +1056,7 @@ def _run_distributed(pg: PartitionedGraph, starts, spec: SamplerSpec,
     cfg = cfg or DistConfig()
     N = pg.num_devices
     if mesh is None:
-        devs = np.array(jax.devices()[:N])
-        mesh = jax.sharding.Mesh(devs, (cfg.axis_name,))
+        mesh = jax.sharding.Mesh(mesh_devices(N), (cfg.axis_name,))
     starts_sh, qcount = shard_starts(starts, N)
     run = make_distributed_engine(pg, spec, cfg, mesh)
     base_key = task_rng.stream_key(seed)

@@ -25,8 +25,14 @@ epoch-salted base key.
 Shared Threefry core
 --------------------
 :func:`threefry2x32` is an explicit, shape-agnostic implementation of the
-Threefry-2x32 block cipher that is bit-equal to ``jax.random``'s
-(``tests/test_fused_step.py`` pins the equality).  It exists so the *same*
+Threefry-2x32 block cipher that is bit-equal to ``jax.random``'s.
+:func:`key_bits` lays draws out as ``jax.random.bits`` does under
+``jax.threefry_partitionable(False)`` — two 32-bit draws per cipher block,
+counters split in halves — which is *not* JAX's default layout since 0.5
+(one block per draw, output words XORed).  ``tests/test_fused_step.py``
+pins the equality against that non-partitionable layout; the walks
+themselves never call ``jax.random`` for draws, so the JAX default does
+not change them.  It exists so the *same*
 derivation runs in two places:
 
   * the vectorized jnp superstep (``task_uniforms`` below — now direct
@@ -222,18 +228,21 @@ def task_key_pair(k0, k1, query_id, hop, salt, epoch=None):
 
 
 def bits_to_uniform(bits):
-    """uint32 random bits -> U[0, 1) float32, exactly as
-    ``jax.random.uniform``: keep the top 23 bits as the mantissa of a
-    float in [1, 2), subtract 1."""
-    f = jax.lax.bitcast_convert_type(
-        (jnp.asarray(bits, jnp.uint32) >> np.uint32(9))
-        | np.uint32(0x3F800000), jnp.float32)
-    return jnp.maximum(f - 1.0, 0.0)
+    """uint32 random bits -> U[0, 1) float32, bit-equal to
+    ``jax.random.uniform``'s mantissa trick (top 23 bits as the mantissa
+    of a float in [1, 2), minus 1): that value is exactly ``m * 2**-23``
+    for ``m = bits >> 9``, and both the int -> float conversion of a
+    23-bit ``m`` and the power-of-two scale are exact.  Written without a
+    bitcast so it also lowers on SMEM scalars inside a Pallas TPU kernel
+    (Mosaic's ``tpu.bitcast`` takes vectors only)."""
+    m = (jnp.asarray(bits, jnp.uint32) >> np.uint32(9)).astype(jnp.int32)
+    return m.astype(jnp.float32) * np.float32(2.0 ** -23)
 
 
 def _counter_pairs(num: int):
-    """Counter words for ``num`` 32-bit draws, split exactly as
-    ``jax.random``'s ``threefry_2x32`` does (odd sizes pad one zero)."""
+    """Counter words for ``num`` 32-bit draws, split as the
+    non-partitionable ``threefry_2x32`` layout does (odd sizes pad one
+    zero)."""
     pairs = (num + 1) // 2
     x0 = np.arange(pairs, dtype=np.uint32)
     x1 = np.where(np.arange(pairs) + pairs < num,
@@ -243,7 +252,8 @@ def _counter_pairs(num: int):
 
 def key_bits(k0, k1, num: int):
     """``num`` uint32 words from a key pair — bit-equal to
-    ``jax.random.bits(key, (num,), jnp.uint32)``.  ``k0``/``k1`` may carry
+    ``jax.random.bits(key, (num,), jnp.uint32)`` under
+    ``jax.threefry_partitionable(False)``.  ``k0``/``k1`` may carry
     leading batch dims; the draw axis is appended last."""
     x0, x1 = _counter_pairs(num)
     k0 = jnp.asarray(k0, jnp.uint32)[..., None]

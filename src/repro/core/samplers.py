@@ -58,17 +58,6 @@ class SamplerSpec:
     stop_prob: float = 0.0  # PPR teleport/termination probability α
     rejection_rounds: int = 12
     reservoir_chunk: int = 64
-    # Degree-adaptive reservoir scan: bound the E-S chunk loop by the live
-    # lanes' actual max degree instead of the graph's max_degree (a pure
-    # machine knob — skipped chunks contribute only -inf reservoir keys, so
-    # sampled paths are bit-identical either way; the dominant win for
-    # weighted Node2Vec on power-law graphs, see fig10 bench).  The default
-    # "auto" lets the Walker gate it on measured degree skew at graph-bind
-    # time (repro.tune.adaptive_chunk_gate: on balanced graphs the dynamic
-    # loop bound buys nothing, so the gate keeps the fixed scan); engines
-    # consuming an unresolved "auto" treat it as truthy (the legacy
-    # always-adaptive behavior).
-    adaptive_chunks: "bool | str" = "auto"
     metapath: Tuple[int, ...] = ()
 
     def __post_init__(self):
@@ -106,10 +95,6 @@ class SamplerSpec:
             raise ValueError(
                 f"reservoir_chunk must be positive, got "
                 f"{self.reservoir_chunk}")
-        if self.adaptive_chunks not in (True, False, "auto"):
-            raise ValueError(
-                f"adaptive_chunks must be True, False, or 'auto', got "
-                f"{self.adaptive_chunks!r}")
 
     @property
     def second_order(self) -> bool:

@@ -217,7 +217,7 @@ class WalkStats(NamedTuple):
                               # device-resident kernel amortizes many
                               # supersteps per launch, so
                               # supersteps / launches is the fusion factor
-    cache_hits: jnp.ndarray   # lane-gathers served from the VMEM hot-vertex
+    cache_hits: jnp.ndarray   # lane-gathers served from the SMEM hot-vertex
                               # cache (fused kernel with cache_budget > 0;
                               # 0 everywhere else)
     cache_misses: jnp.ndarray  # lane-gathers that fell through to the HBM
@@ -236,7 +236,7 @@ class WalkStats(NamedTuple):
         return self.supersteps / jnp.maximum(self.launches, 1)
 
     def cache_hit_rate(self):
-        """Fraction of cache probes (leader gathers) served from VMEM."""
+        """Fraction of cache probes (leader gathers) served from SMEM."""
         return self.cache_hits / jnp.maximum(
             self.cache_hits + self.cache_misses, 1)
 

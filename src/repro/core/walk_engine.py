@@ -110,7 +110,7 @@ class EngineConfig:
     hops_per_launch: int = 16      # fused only: supersteps per kernel launch
                                    # (the k of the O(k·state) -> O(state)
                                    # host-traffic reduction)
-    cache_budget: int = 0          # fused only: byte budget of the VMEM
+    cache_budget: int = 0          # fused only: byte budget of the SMEM
                                    # hot-vertex adjacency cache (0 = off);
                                    # gathers on cached hubs skip the HBM
                                    # DMA loops, bit-identically
@@ -338,10 +338,12 @@ def _advance_controller(queue: QueryQueue, head_hist: jnp.ndarray,
     return queue._replace(staged=staged), head_hist
 
 
-def _process(graph: CSRGraph, spec: SamplerSpec, cfg: EngineConfig, base_key,
-             slots: WalkerSlots, paths, lengths, done):
+def _process(graph: CSRGraph, rows, spec: SamplerSpec, cfg: EngineConfig,
+             base_key, slots: WalkerSlots, paths, lengths, done):
     """One hop for every live lane: Row Access → Sampling → Column Access →
-    terminate (paper Alg. II.1 lines 5-9, vectorized over lanes)."""
+    terminate (paper Alg. II.1 lines 5-9, vectorized over lanes).  ``rows``
+    is the graph's row layout (:func:`_kernel_rows`) for the Pallas
+    walk-step kernel."""
     A = slots.active
 
     # PPR teleport/termination draw (before the hop; geometric walk length).
@@ -359,13 +361,12 @@ def _process(graph: CSRGraph, spec: SamplerSpec, cfg: EngineConfig, base_key,
             u = task_rng.task_uniforms(base_key, slots.query_id, slots.hop,
                                        1, SALT_COLUMN, epoch=slots.epoch)
             v_next, deg = walk_ops.walk_step_uniform(
-                slots.v_curr, u[:, 0], graph.row_ptr, graph.col)
+                slots.v_curr, u[:, 0], rows)
         else:
             u = task_rng.task_uniforms(base_key, slots.query_id, slots.hop,
                                        2, SALT_COLUMN, epoch=slots.epoch)
             v_next, deg = walk_ops.walk_step_alias(
-                slots.v_curr, u[:, 0], u[:, 1], graph.row_ptr, graph.col,
-                graph.alias_prob, graph.alias_idx)
+                slots.v_curr, u[:, 0], u[:, 1], rows)
         ok = deg > 0
     else:
         addr, deg = row_access(graph, slots.v_curr)           # stage 1
@@ -398,13 +399,13 @@ def _process(graph: CSRGraph, spec: SamplerSpec, cfg: EngineConfig, base_key,
     return new_slots, terminated, adv, paths, lengths, done
 
 
-def _superstep(graph, spec, cfg, base_key, depth,
+def _superstep(graph, rows, spec, cfg, base_key, depth,
                state: StreamState) -> StreamState:
     slots, queue, paths, lengths, done, stats, head_hist = state
     W = cfg.num_slots
 
     slots, terminated, adv, paths, lengths, done = _process(
-        graph, spec, cfg, base_key, slots, paths, lengths, done)
+        graph, rows, spec, cfg, base_key, slots, paths, lengths, done)
 
     n_active = jnp.sum(slots.active.astype(jnp.int32))
     idle = W - n_active
@@ -429,6 +430,15 @@ def _superstep(graph, spec, cfg, base_key, depth,
     slots, queue, paths, lengths = _refill(slots, queue, paths, lengths, cfg,
                                            terminated)
     return StreamState(slots, queue, paths, lengths, done, stats, head_hist)
+
+
+def _kernel_rows(graph: CSRGraph, spec: SamplerSpec, cfg: EngineConfig):
+    """The graph's row layout for the Pallas kernels, built once per run
+    before the superstep loop (None for the jnp superstep)."""
+    if cfg.step_impl == "jnp":
+        return None
+    from repro.kernels.common import graph_rows
+    return graph_rows(graph, spec.reservoir_chunk)
 
 
 def _work_left(state: StreamState):
@@ -465,6 +475,7 @@ def make_superstep_runner(spec: SamplerSpec, cfg: EngineConfig, cache=None):
                            k) -> StreamState:
             base_key = task_rng.stream_key(seed)
             k = jnp.asarray(k, jnp.int32)
+            rows = _kernel_rows(graph, spec, cfg)
 
             def cond(carry):
                 i, st = carry
@@ -473,7 +484,7 @@ def make_superstep_runner(spec: SamplerSpec, cfg: EngineConfig, cache=None):
             def body(carry):
                 i, st = carry
                 kc = jnp.minimum(cfg.hops_per_launch, k - i)
-                return i + kc, launch(graph, st, base_key, kc)
+                return i + kc, launch(graph, rows, st, base_key, kc)
 
             _, state = jax.lax.while_loop(
                 cond, body, (jnp.zeros((), jnp.int32), state))
@@ -485,7 +496,8 @@ def make_superstep_runner(spec: SamplerSpec, cfg: EngineConfig, cache=None):
     def run_supersteps(graph: CSRGraph, state: StreamState, seed,
                        k) -> StreamState:
         base_key = task_rng.stream_key(seed)
-        step = partial(_superstep, graph, spec, cfg, base_key, depth)
+        step = partial(_superstep, graph, _kernel_rows(graph, spec, cfg),
+                       spec, cfg, base_key, depth)
 
         def cond(carry):
             i, st = carry
@@ -547,6 +559,7 @@ def build_engine(spec: SamplerSpec, cfg: EngineConfig, cache=None):
             jnp.zeros((cfg.num_slots,), bool))
         state = state._replace(slots=slots, queue=queue, paths=paths,
                                lengths=lengths, head_hist=head_hist)
+        rows = _kernel_rows(graph, spec, cfg)
 
         def cond(st):
             return _work_left(st) & (st.stats.supersteps < cfg.max_supersteps)
@@ -555,11 +568,12 @@ def build_engine(spec: SamplerSpec, cfg: EngineConfig, cache=None):
             def body(st):
                 kc = jnp.minimum(cfg.hops_per_launch,
                                  cfg.max_supersteps - st.stats.supersteps)
-                return fused_launch(graph, st, base_key, kc)
+                return fused_launch(graph, rows, st, base_key, kc)
 
             state = jax.lax.while_loop(cond, body, state)
         else:
-            step = partial(_superstep, graph, spec, cfg, base_key, depth)
+            step = partial(_superstep, graph, rows, spec, cfg, base_key,
+                           depth)
             state = jax.lax.while_loop(cond, step, state)
         return WalkResult(paths=state.paths, lengths=state.lengths,
                           stats=state.stats)

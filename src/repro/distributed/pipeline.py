@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
@@ -62,9 +61,9 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
         buf, outs = jax.lax.fori_loop(0, T, step, (buf, outs))
         return outs[None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(None)),
-                   out_specs=P(axis), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(None)),
+                       out_specs=P(axis), check_vma=False)
     outs = fn(params_stacked, x_microbatches)
     # every stage returns a buffer; only the last stage's is valid
     return outs[-1]

@@ -1,4 +1,4 @@
-"""Hot-vertex adjacency cache: the VMEM tier of the gather hierarchy.
+"""Hot-vertex adjacency cache: the SMEM tier of the gather hierarchy.
 
 Power-law graphs concentrate most gather traffic on a handful of hub
 vertices (a walking lane occupies a vertex with probability proportional
@@ -56,7 +56,7 @@ def vertex_overhead_bytes(payloads: Sequence[str],
 
 @dataclasses.dataclass(frozen=True)
 class HotVertexCache:
-    """The packed VMEM-resident block plus its id → slot directory.
+    """The packed SMEM-resident block plus its id → slot directory.
 
     ``hot_ids`` is sorted ascending so the kernel's probe is a static
     ``ceil(log2(H+1))``-trip binary search; ``hot_off`` is the exclusive

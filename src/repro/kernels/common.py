@@ -21,14 +21,16 @@ small unroll counts on that argument.
 
 The `ScheduleBuilder` emitters mirror the generic loop shapes
 (`walk_step.row_access_loop`/`gather1_loop`/`gather2_loop`, the fused
-kernel's ping-pong chunk loop and delayed-wait write-back); each kernel
+kernel's ping-pong chunk loop and per-superstep record flush); each kernel
 composes them into its full schedule.  Keep an emitter and its loop in
 the same module so a pipeline change and its declared schedule travel in
 one diff.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Sequence, Tuple
+import dataclasses
+from functools import partial
+from typing import Any, NamedTuple, Sequence, Tuple
 
 import jax
 
@@ -52,10 +54,10 @@ class DmaOp(NamedTuple):
         actually accumulates.
 
     ``tier`` names the memory tier the op touches: ``"hbm"`` (the
-    default — every async-copy staging buffer) or ``"vmem"`` for the
-    hot-vertex cache block, which is launch-resident and therefore never
-    the target of a copy.  A ``read`` with ``tier="vmem"`` needs no
-    dominating wait (the data is always resident); a ``start`` on a vmem
+    default — every async-copy staging buffer) or ``"smem"`` for the
+    hot-vertex cache block, which is launch-resident in SMEM and therefore
+    never the target of a copy.  A ``read`` with ``tier="smem"`` needs no
+    dominating wait (the data is always resident); a ``start`` on an smem
     buffer is by definition a *phantom copy* — a hit path issuing HBM
     traffic it was built to avoid — and the DMA pass flags it.
     """
@@ -93,10 +95,10 @@ class ScheduleBuilder:
         self.ops.append(DmaOp("read", buffer, slot, tier=tier))
 
     def cache_read(self, buffer: str) -> None:
-        """A hit-path read of the VMEM-resident hot-vertex cache: no
+        """A hit-path read of the on-chip hot-vertex cache: no
         copy, no wait — the declarative record of "this gather issued no
         HBM traffic" that the DMA pass verifies cached schedules by."""
-        self.read(buffer, 0, tier="vmem")
+        self.read(buffer, 0, tier="smem")
 
     def write(self, buffer: str, slot: int) -> None:
         self.ops.append(DmaOp("write", buffer, slot))
@@ -141,20 +143,19 @@ class ScheduleBuilder:
                 for b in buffers:
                     self.read(b, c % 2)
 
-    def writeback_loop(self, buffer: str, n: int = 4) -> None:
-        """The fused kernel's async path write-back shape: per record,
-        reclaim the staging slot by waiting its two-records-old store,
-        overwrite it, start the outbound copy; drain both slots at the
-        end of the launch."""
-        pend: list[int] = []
-        for c in range(n):
-            if c >= 2:
-                self.wait(buffer, (c - 2) % 2, pend[c - 2])
-            self.write(buffer, c % 2)
-            pend.append(self.start(buffer, c % 2))
-        for back in (2, 1):
-            if n >= back:
-                self.wait(buffer, (n - back) % 2, pend[n - back])
+    def flush_loop(self, buffer: str, n: int = 3) -> None:
+        """The fused kernel's per-superstep record flush: one staging
+        slot, rewritten once per superstep after the previous flush out
+        of it has been waited (the flush overlaps the next superstep's
+        gathers), and drained at the end of the launch."""
+        pend = None
+        for _ in range(n):
+            if pend is not None:
+                self.wait(buffer, 0, pend)
+            self.write(buffer, 0)
+            pend = self.start(buffer, 0)
+        if pend is not None:
+            self.wait(buffer, 0, pend)
 
 
 def schedule_buffers(ops: Sequence[DmaOp]) -> Tuple[str, ...]:
@@ -177,3 +178,85 @@ def default_interpret(interpret: bool | None) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
+
+
+# --------------------------------------------------------------------------
+# Row-tiled HBM layout for scalar gathers.
+#
+# Mosaic refuses DMAs of one or two words out of a 1-D HBM array (a 1-D
+# slice must be a multiple of the 1024-word tiling), and out of an (N, 1)
+# column.  It accepts whole 128-lane rows of an (R, 128) array at any
+# dynamic row.  So every array the walk kernels gather single words from
+# is laid out as (R, 128) rows: a gather copies the row(s) holding the
+# word(s) into an SMEM staging slot and picks the word out — flat word
+# ``e`` lives at ``[e // LANES, e % LANES]``, the same address arithmetic
+# as the 1-D array, so every gathered value is unchanged.
+# --------------------------------------------------------------------------
+
+LANES = 128
+
+
+def span_rows(width: int) -> int:
+    """Rows a ``width``-word window spans at the worst alignment (its
+    first word at lane 127)."""
+    return (int(width) + LANES - 2) // LANES + 1
+
+
+def as_rows(x, extra_rows: int = 2):
+    """Flatten ``x`` into ``(R, LANES)`` rows with at least ``extra_rows``
+    rows of zero padding past the last word, so a fixed-size window DMA
+    that starts at any valid word stays inside the array."""
+    import jax.numpy as jnp
+    flat = jnp.ravel(x)
+    n = flat.shape[0]
+    rows = -(-n // LANES) + int(extra_rows)
+    return jnp.pad(flat, (0, rows * LANES - n)).reshape(rows, LANES)
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["row_ptr", "col", "weights", "alias_prob", "alias_idx",
+                      "type_offsets"],
+         meta_fields=["num_vertices", "num_edges"])
+@dataclasses.dataclass(frozen=True)
+class GraphRows:
+    """A graph's gather operands in the row layout, built once per run by
+    :func:`graph_rows` — outside the engine's superstep loop — and shared
+    by the walk kernels (`walk_step`, `fused_superstep`).  An array the
+    graph lacks is a one-row zero placeholder, so the kernels' operand
+    lists keep one shape."""
+
+    row_ptr: Any
+    col: Any
+    weights: Any
+    alias_prob: Any
+    alias_idx: Any
+    type_offsets: Any
+    num_vertices: int
+    num_edges: int
+
+
+def graph_rows(graph, window: int = 1) -> GraphRows:
+    """Lay out ``graph``'s CSR arrays as rows.  ``window`` is the widest
+    run of consecutive words one DMA copies out of ``col``/``weights``
+    (the reservoir chunk); they get the padding rows such a window can
+    overrun."""
+    import jax.numpy as jnp
+    pad = max(2, span_rows(window))
+
+    def rows(x, dtype, extra=2):
+        return as_rows(jnp.zeros((1,), dtype) if x is None else x, extra)
+
+    return GraphRows(
+        row_ptr=rows(graph.row_ptr, jnp.int32),
+        col=rows(graph.col, jnp.int32, pad),
+        weights=rows(graph.weights, jnp.float32, pad),
+        alias_prob=rows(graph.alias_prob, jnp.float32),
+        alias_idx=rows(graph.alias_idx, jnp.int32),
+        type_offsets=rows(graph.type_offsets, jnp.int32),
+        num_vertices=int(graph.row_ptr.shape[0]) - 1,
+        num_edges=int(graph.col.shape[0]))
+
+
+def flat_words(rows, n: int):
+    """The first ``n`` words of a row-tiled array, as a 1-D array."""
+    return rows.reshape(-1)[:n]

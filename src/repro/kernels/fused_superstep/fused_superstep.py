@@ -10,23 +10,27 @@ instead keeps the whole machine resident on the device across ``k``
 supersteps per launch:
 
   * **WalkerSlots + queue counters + stats stay in SMEM** for the entire
-    launch (the paper's single-pipeline-word task tuples, §V-A); the
-    staged query ring (order / start / epoch by slot id) is SMEM-resident
-    too, so zero-bubble refill is pure scalar work.
+    launch (the paper's single-pipeline-word task tuples, §V-A).  The
+    arrival ring stays in HBM: each superstep prefetches the window of at
+    most W arrivals refill can consume (slot id / start / epoch in ring
+    order, from the current head), so zero-bubble refill is pure scalar
+    work and no Q-sized array occupies SMEM.
   * **In-kernel ThundeRiNG analogue**: per-task uniforms are derived on
     SMEM scalars via the shared :func:`repro.core.rng.threefry2x32` —
     the same fold chain as the jnp path, so draws are bit-identical and
     no random bits ever touch HBM (§VII).
   * **Graph gathers stay asynchronous**: row access / column access /
-    alias-table probes issue the same double-buffered one-and-two-element
-    DMAs as `kernels/walk_step`, overlapping lane *i+1*'s fetch with lane
-    *i*'s sampling arithmetic (§V-B).
-  * **Async write-back**: only the per-hop path records stream out to the
-    HBM-resident path buffer through a two-slot staging buffer whose
-    outbound copies stay in flight across records — a slot is reclaimed
-    by waiting its two-records-old store, and both slots drain at the end
-    of the launch (the paper's §IV-B streaming-window write-back);
-    ``done``/``lengths`` ride home once per launch with the SMEM state.
+    alias-table probes issue the same double-buffered row DMAs as
+    `kernels/walk_step` (the graph arrays are 128-lane rows; a gather
+    copies the row holding its word, or the two rows holding a pair),
+    overlapping lane *i+1*'s fetch with lane *i*'s sampling arithmetic
+    (§V-B).
+  * **Async write-back**: each superstep stages one record per lane
+    (query, new hop, vertex, done flag, refilled query and its start) and
+    streams the block to an HBM log while the next superstep gathers (the
+    paper's §IV-B streaming-window write-back).  The wrapper scatters the
+    log into ``done`` / ``paths`` / ``lengths`` after the launch — the
+    chip's DMA engine cannot store single words into those arrays.
   * **In-kernel termination + zero-bubble refill**: the PPR stop draw,
     hop budget, dead-end detection, prefix-sum lane compaction, and the
     Theorem VI.1 staging controller all run between hops without leaving
@@ -57,8 +61,8 @@ stages its gather/score phases through the DMA machinery here —
     leave SMEM;
   * ``reservoir_n2v`` (weighted Node2Vec): the ``chunked_loop`` schedule
     runs in-kernel — a degree-adaptive chunk loop (trip count
-    ``ceil(deg/chunk)`` per lane, the in-kernel form of the jnp path's
-    ``adaptive_chunks`` trip bounding) streams each lane's CSR segment
+    ``ceil(deg/chunk)`` per lane: like the jnp scan, each lane's work
+    follows its own degree) streams each lane's CSR segment
     through ping-pong (2, chunk) column/weight DMA buffers (chunk c+1's
     fetch in flight while chunk c is scored), and the Efraimidis–
     Spirakis reservoir carry (running E-S key + winning offset per
@@ -71,10 +75,11 @@ memory traffic — there is no jnp fallback path left in the engine.
 Semantics are pinned bit-identical to the jnp superstep
 (`core/walk_engine.py`) for every sampler, including PPR stop draws,
 both scheduling modes, and the open-system ring economy —
-``tests/test_fused_step.py``.  Layout note: slot state is (W,) and the
-query ring (Q,) in SMEM, which assumes the modest W/Q of a single core's
-lane pool; the HBM-resident buffers (graph CSR, edge weights, alias
-tables, type_offsets, paths) are unbounded.
+``tests/test_fused_step.py``.  Layout note: slot state is (W,) in SMEM
+(1 MiB on a v5e core, shared with the staging buffers and the hot-vertex
+cache — `ops.smem_bytes` accounts for it); everything Q- or graph-sized
+(CSR, edge weights, alias tables, type_offsets, the arrival ring, the
+record log) stays in HBM.
 """
 from __future__ import annotations
 
@@ -89,8 +94,9 @@ from repro.core import rng
 from repro.core.rng import SALT_CHUNK0, SALT_COLUMN, SALT_STOP
 from repro.core.samplers import _uniform_index
 from repro.core.tasks import WalkStats
-from repro.kernels.common import ScheduleBuilder
-from repro.kernels.walk_step.walk_step import (cached_gather1_loop,
+from repro.kernels.common import LANES, ScheduleBuilder
+from repro.kernels.walk_step.walk_step import (_rows_copy, _word,
+                                               cached_gather1_loop,
                                                cached_gather2_loop,
                                                gather1_loop, gather2_loop,
                                                row_access_loop)
@@ -101,9 +107,8 @@ NUM_STATS = len(WalkStats._fields)
 
 
 def dma_schedule(kind: str = "uniform", lanes: int = 3, rounds: int = 2,
-                 bisect_iters: int = 2, chunks: int = 3, records: int = 4,
-                 record_paths: bool = True, cached: bool = False,
-                 probe_trips: int = 2):
+                 bisect_iters: int = 2, chunks: int = 3, records: int = 3,
+                 cached: bool = False, probe_trips: int = 2):
     """Declarative DMA schedule of one fused-superstep launch, for the
     static hazard analyzer (`repro.analysis.dma_hazards`).
 
@@ -124,12 +129,15 @@ def dma_schedule(kind: str = "uniform", lanes: int = 3, rounds: int = 2,
         ping-pong (``ckcol``, ``ckwgt``) chunk loop with chunk c+1 in
         flight while chunk c's bisection probes (``colbuf``) and E-S fold
         consume the staged chunk, then the final column access;
-      * the async path write-back (``wbuf``) with its delayed two-deep
-        slot reclamation and end-of-launch drain.
+      * the arrival-ring window (``qbuf``), started at the top of the
+        superstep and waited by refill;
+      * the per-superstep record flush (``wbuf``, ``records``
+        supersteps): one staging slot, waited before it is rewritten and
+        drained at the end of the launch.
 
     ``cached=True`` emits the *fully-hit* representative superstep of the
     gather hierarchy (``cache_budget > 0`` and every lane's v_curr hot):
-    every v_curr-keyed gather becomes vmem-tier ``cache.*`` reads — the
+    every v_curr-keyed gather becomes smem-tier ``cache.*`` reads — the
     directory probe (``probe_trips`` binary-search reads plus the
     (addr, deg) payload on ``cache.idx``) replaces the RP_entry loop, and
     the column / alias / typed / chunk payloads read ``cache.col`` /
@@ -137,7 +145,7 @@ def dma_schedule(kind: str = "uniform", lanes: int = 3, rounds: int = 2,
     while the v_prev-keyed loops (the second-order samplers' pair fetch,
     bisection probes, and membership checks) and the path write-back keep
     their HBM copies.  The DMA pass proves hit paths issue **no** copies
-    (a ``start`` on a vmem buffer is a phantom copy) and the surviving
+    (a ``start`` on an smem buffer is a phantom copy) and the surviving
     miss-side loops stay wait-dominated.  Partially-hit supersteps
     interleave this schedule with the uncached one per lane, so the two
     declarations jointly cover every execution.
@@ -147,6 +155,7 @@ def dma_schedule(kind: str = "uniform", lanes: int = 3, rounds: int = 2,
     above.
     """
     b = ScheduleBuilder()
+    window = b.start("qbuf", 0)                     # arrival-ring window
 
     def probe():
         # Directory probe: the binary search over the sorted hot-id list,
@@ -227,8 +236,9 @@ def dma_schedule(kind: str = "uniform", lanes: int = 3, rounds: int = 2,
         col_gather()                                # final column access
     else:  # uniform / ppr
         col_gather()
-    if record_paths:
-        b.writeback_loop("wbuf", records)           # async path write-back
+    b.wait("qbuf", 0, window)                       # refill consumes it
+    b.read("qbuf", 0)
+    b.flush_loop("wbuf", records)                   # superstep records
     return b.ops
 
 
@@ -307,7 +317,7 @@ def _cached_row_access(W, num_vertices, cache, rp_ref, rpbuf, rpsem,
                        vcur, act, addr_scr, deg_scr, lead_scr,
                        tagv_scr, tagl_scr, stats):
     """Row access through the gather hierarchy: same-vertex coalescing →
-    VMEM directory probe → HBM RP_entry DMAs for miss leaders only.
+    SMEM directory probe → HBM RP_entry DMAs for miss leaders only.
 
     Pass 1 fills a direct-mapped tag table (vertex → writing lane) in
     *reverse* lane order, so the surviving writer of each tag slot is
@@ -357,8 +367,7 @@ def _cached_row_access(W, num_vertices, cache, rp_ref, rpbuf, rpsem,
         return (lead_scr[ii] == ii) & (cache.cslot[ii] < 0)
 
     def copy(i, slot):
-        return pltpu.make_async_copy(rp_ref.at[pl.ds(vv_of(i), 2)],
-                                     rpbuf.at[slot], rpsem.at[slot])
+        return _rows_copy(rp_ref, vv_of(i), 2, rpbuf, rpsem, slot)
 
     def body(i, _):
         slot = jax.lax.rem(i, 2)
@@ -370,8 +379,10 @@ def _cached_row_access(W, num_vertices, cache, rp_ref, rpbuf, rpsem,
         @pl.when(need(i))
         def _():
             copy(i, slot).wait()
-            addr_scr[i] = rpbuf[slot, 0]
-            deg_scr[i] = rpbuf[slot, 1] - rpbuf[slot, 0]
+            off = jax.lax.rem(vv_of(i), LANES)
+            lo = _word(rpbuf, slot, off)
+            addr_scr[i] = lo
+            deg_scr[i] = _word(rpbuf, slot, off + 1) - lo
 
         @pl.when((lead_scr[i] == i) & (cache.cslot[i] >= 0))
         def _():
@@ -440,9 +451,8 @@ def _rejection_sample(W, num_vertices, num_edges, K, inv_p, inv_q,
 
     # RP_entry pair of v_prev per lane: the verify phase's bisection
     # bounds, plus the lane's folded key pair and accept state.
-    def vp_src(i):
-        vp = jnp.clip(vprev[i], 0, num_vertices - 1)
-        return rp_ref.at[pl.ds(vp, 2)]
+    def vp_word(i):
+        return jnp.clip(vprev[i], 0, num_vertices - 1)
 
     def on_vp(i, lo, hi):
         plo_scr[i] = lo
@@ -454,7 +464,7 @@ def _rejection_sample(W, num_vertices, num_edges, K, inv_p, inv_q,
         got_scr[i] = 0
         vnext_scr[i] = 0
 
-    gather2_loop(W, vp_src, pairbuf, pairsem, on_vp)
+    gather2_loop(W, vp_word, rp_ref, pairbuf, pairsem, on_vp)
 
     def round_body(j, _):
         ju = j.astype(jnp.uint32)
@@ -511,14 +521,15 @@ def _rejection_sample(W, num_vertices, num_edges, K, inv_p, inv_q,
     jax.lax.fori_loop(0, K, round_body, 0)
 
 
-def _metapath_sample(W, num_vertices, num_edges, mp_sched, to_ref, col_ref,
+def _metapath_sample(W, num_vertices, num_edges, mp_sched, to_width, to_ref,
+                     col_ref,
                      colbuf, colsem, pairbuf, pairsem,
                      vcur, hop_o, u0_scr, addr_scr, deg_scr, idx_scr,
                      vnext_scr, cache=None):
     """In-kernel lowering of the metapath program's gather(typed) +
     score(pick_uniform) phases: the scheduled type's packed sub-segment
-    bounds (``type_offsets[v, t:t+2]``) ride the double-buffered
-    2-element DMA loop (lane i+1's bounds in flight while lane i picks),
+    bounds (``type_offsets[v, t:t+2]``) ride the double-buffered pair
+    DMA loop (lane i+1's bounds in flight while lane i picks),
     the staged uniform picks within the sub-segment, and a no-match
     sub-segment zeroes the lane's effective degree (early termination,
     same as the jnp executor).  With ``cache``, hit lanes take their
@@ -534,9 +545,11 @@ def _metapath_sample(W, num_vertices, num_edges, mp_sched, to_ref, col_ref,
             t = jnp.where(r == s, jnp.int32(mp_sched[s]), t)
         return t
 
-    def seg_src(i):
+    def seg_word(i):
+        # Flat word of type_offsets[v, t] in the row-tiled (N*(T+1),)
+        # layout; its pair partner t+1 is the next word.
         v_safe = jnp.clip(vcur[i], 0, num_vertices - 1)
-        return to_ref.at[v_safe, pl.ds(seg_t(i), 2)]
+        return v_safe * to_width + seg_t(i)
 
     def on_seg(i, base, end):
         cnt = end - base
@@ -550,10 +563,10 @@ def _metapath_sample(W, num_vertices, num_edges, mp_sched, to_ref, col_ref,
             t = seg_t(i)
             return cache.toff[s, t], cache.toff[s, t + 1]
 
-        cached_gather2_loop(W, seg_src, pairbuf, pairsem, on_seg,
+        cached_gather2_loop(W, seg_word, to_ref, pairbuf, pairsem, on_seg,
                             hit_fn=cache.hit_fn(), hit_pair_fn=hit_pair)
     else:
-        gather2_loop(W, seg_src, pairbuf, pairsem, on_seg)
+        gather2_loop(W, seg_word, to_ref, pairbuf, pairsem, on_seg)
 
     def on_col(i, v):
         vnext_scr[i] = v
@@ -563,7 +576,7 @@ def _metapath_sample(W, num_vertices, num_edges, mp_sched, to_ref, col_ref,
         cache.col if cache is not None else None)
 
 
-def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
+def _reservoir_sample(W, num_vertices, num_edges, CH, CR, inv_p, inv_q,
                       max_degree, has_weights, k0, k1,
                       rp_ref, col_ref, wgt_ref,
                       colbuf, colsem, pairbuf, pairsem,
@@ -576,13 +589,11 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
     weighted reservoir scan (weighted Node2Vec) as a degree-adaptive
     chunk loop per lane.
 
-    Per lane, the trip count is ``ceil(deg/CH)`` (the in-kernel form of
-    the jnp path's ``adaptive_chunks`` bounding: chunks past a lane's
-    own degree contribute only -inf reservoir keys, so truncating the
-    loop there cannot change the scanned argmax — the kernel is
-    degree-adaptive per lane regardless of the spec flag).  Chunk c of
-    (column, edge weight) streams through ping-pong ``(2, Lc)`` DMA
-    buffers with chunk c+1's fetch in flight while chunk c is scored;
+    Per lane, the trip count is ``ceil(deg/CH)`` (chunks past a lane's
+    own degree contribute only -inf reservoir keys, so stopping the
+    loop there cannot change the scanned argmax).  Chunk c of
+    (column, edge weight) streams through ping-pong ``(2, CR, 128)`` DMA
+    buffers (the CR rows that can hold a CH-word window) with chunk c+1's fetch in flight while chunk c is scored;
     the per-chunk uniforms reproduce ``rng.task_uniforms(..., CH,
     SALT_CHUNK0 + c)``'s counter layout exactly; the (p, q) bias
     bisects all CH candidates in N(v_prev) breadth-wise (identical trip
@@ -606,9 +617,8 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
 
     # v_prev RP_entry pair per lane (bias bisection bounds), plus the
     # reservoir carry init.
-    def vp_src(i):
-        vp = jnp.clip(vprev[i], 0, num_vertices - 1)
-        return rp_ref.at[pl.ds(vp, 2)]
+    def vp_word(i):
+        return jnp.clip(vprev[i], 0, num_vertices - 1)
 
     def on_vp(i, lo, hi):
         plo_scr[i] = lo
@@ -616,7 +626,7 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
         bkey_scr[i] = -jnp.inf
         cand_scr[i] = 0
 
-    gather2_loop(W, vp_src, pairbuf, pairsem, on_vp)
+    gather2_loop(W, vp_word, rp_ref, pairbuf, pairsem, on_vp)
 
     def lane_scan(i, _):
         deg = deg_scr[i]
@@ -644,17 +654,19 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
                     fn()
 
             def ck_copies(c, slot):
-                # Chunk DMAs are fixed-length Lc; near the end of `col`
-                # the base clamps down and valid positions shift by
-                # `off` inside the buffer (invalid positions past the
-                # lane's degree are masked out of the fold anyway).
-                base = jnp.clip(addr + c * CH, 0, num_edges - Lc)
+                # A chunk is the CR rows that hold words [addr + c*CH,
+                # addr + c*CH + CH) of the row-tiled `col` (and weights);
+                # position j sits at word `off + j` of the staged rows.
+                # The row arrays carry CR rows of padding, so the copy
+                # never leaves them; positions past the lane's degree
+                # are masked out of the fold.
+                row = (addr + c * CH) // LANES
                 cps = [pltpu.make_async_copy(
-                    col_ref.at[pl.ds(base, Lc)], ckcol.at[slot],
+                    col_ref.at[pl.ds(row, CR)], ckcol.at[slot],
                     cksem.at[slot, 0])]
                 if has_weights:
                     cps.append(pltpu.make_async_copy(
-                        wgt_ref.at[pl.ds(base, Lc)], ckwgt.at[slot],
+                        wgt_ref.at[pl.ds(row, CR)], ckwgt.at[slot],
                         cksem.at[slot, 1]))
                 return cps
 
@@ -681,8 +693,7 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
 
                 when_miss(_drain)
 
-                base = jnp.clip(addr + c * CH, 0, num_edges - Lc)
-                off = addr + c * CH - base
+                off = jax.lax.rem(addr + c * CH, LANES)
 
                 def cache_e(j):
                     # Cache-space index of chunk position j (addr is the
@@ -692,8 +703,7 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
 
                 def cand(j):
                     # chunk_gather's staging: invalid positions -> -1.
-                    b = jnp.minimum(off + j, Lc - 1)
-                    val = ckcol[slot, b]
+                    val = _word(ckcol, slot, off + j)
                     if cache is not None:
                         val = jnp.where(hit, cache.col[cache_e(j)], val)
                     return jnp.where(c * CH + j < deg, val, -1)
@@ -757,9 +767,8 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
                 def fold(j, _f):
                     valid = c * CH + j < deg
                     y = cand(j)
-                    b = jnp.minimum(off + j, Lc - 1)
                     if has_weights:
-                        wv = ckwgt[slot, b]
+                        wv = _word(ckwgt, slot, off + j)
                         if cache is not None and cache.wgt is not None:
                             wv = jnp.where(hit, cache.wgt[cache_e(j)], wv)
                         w_edge = jnp.where(valid, wv, 0.0)
@@ -802,35 +811,50 @@ def _reservoir_sample(W, num_vertices, num_edges, CH, Lc, inv_p, inv_q,
         cache.col if cache is not None else None)
 
 
+# Fields of the per-superstep record the kernel streams to HBM; the
+# wrapper (`ops.build_fused_launch`) scatters them into done / paths /
+# lengths after the launch.
+REC_FIELDS = ("query", "hop", "vertex", "done", "new_query", "new_start")
+REC = {f: i for i, f in enumerate(REC_FIELDS)}
+# Fields of the arrival-ring window (ring order, from the launch's head),
+# stacked as row blocks of one (R, 128) HBM array.  Each field is copied
+# by its own 2-D row DMA: a strided copy across the field axis of a 3-D
+# array crashed the chip at unaligned row offsets.
+WIN_FIELDS = ("query", "start", "epoch")
+
+
 def fused_superstep_kernel(
         # ---- static configuration (bound via functools.partial) ----
-        num_vertices, num_edges, W, Q, max_hops, depth, delay,
-        stop_prob, kind, mp_sched, rej_rounds, inv_p, inv_q, max_degree,
-        res_chunk, res_len, has_weights, static_mode, record_paths,
-        use_cache, num_hot, cache_probe_trips, cache_len,
+        num_vertices, num_edges, W, max_hops, depth, delay,
+        stop_prob, kind, mp_sched, to_width, rej_rounds, inv_p, inv_q,
+        max_degree, res_chunk, res_rows, has_weights, static_mode,
+        use_cache, num_hot, cache_probe_trips, cache_len, rec_rows,
         # ---- inputs ----
         key_ref, ctl_ref,
         vcur_in, vprev_in, qid_in, hop_in, act_in, ep_in,
-        qctr_in, hist_in, stats_in, done_in, len_in,
-        qstart_ref, qorder_ref, qepoch_ref,
+        qctr_in, hist_in, stats_in, qwin_ref,
         rp_ref, col_ref, wgt_ref, prob_ref, alias_ref, to_ref,
         chot_ref, cdeg_ref, coff_ref, ccol_ref, cwgt_ref, cprob_ref,
-        cali_ref, ctoff_ref, paths_in,
+        cali_ref, ctoff_ref, log_in,
         # ---- outputs ----
         vcur, vprev, qid_o, hop_o, act, ep_o,
-        qctr, hist, stats, done, len_o, paths,
+        qctr, hist, stats, log,
         # ---- scratch ----
         stop_scr, u0_scr, u1_scr, addr_scr, deg_scr, idx_scr, vnext_scr,
         term_scr,
         rpbuf, rpsem, colbuf, colsem, probbuf, probsem, aliasbuf, aliassem,
-        wbuf, wsem, wmeta, wcnt, pairbuf, pairsem,
+        recbuf, recsem, wcnt, qbuf, qsem, pairbuf, pairsem,
         plo_scr, phi_scr, blo_scr, bhi_scr, kq0_scr, kq1_scr, cand_scr,
         got_scr, bkey_scr, ures_scr, fnd_scr, ckcol, ckwgt, cksem,
         cslot_scr, lead_scr, tagv_scr, tagl_scr):
-    del paths_in  # aliased with `paths` (input_output_aliases)
+    del log_in  # aliased with `log` (input_output_aliases)
     alias = kind == "alias"
     k0 = key_ref[0]
     k1 = key_ref[1]
+    lane_rows = (W + LANES - 1) // LANES
+    win_rows = qbuf.shape[1]
+    field_rows = qwin_ref.shape[0] // len(WIN_FIELDS)
+    head0 = qctr_in[0]
     wcnt[0] = 0
     # The gather-hierarchy context: None when cache_budget == 0, so the
     # cache-off kernel traces exactly the pre-cache pipeline.
@@ -845,27 +869,23 @@ def fused_superstep_kernel(
             ctoff_ref if kind == "metapath" else None,
             cslot_scr)
 
-    def path_write(q, h, v):
-        """Async double-buffered single-record path write-back: start the
-        HBM store for this record and only wait when its staging slot is
-        needed again two writes later — lane i+1's sampling overlaps lane
-        i's store, like the row/column gathers."""
-        c = wcnt[0]
-        slot = jax.lax.rem(c, 2)
+    def rec(field, i, value):
+        recbuf[REC[field] * lane_rows + i // LANES,
+               jax.lax.rem(i, LANES)] = value
 
-        @pl.when(c >= 2)
-        def _():  # reclaim the slot: drain its in-flight store
-            pltpu.make_async_copy(
-                wbuf.at[slot],
-                paths.at[wmeta[slot, 0], pl.ds(wmeta[slot, 1], 1)],
-                wsem.at[slot]).wait()
+    def flush_copy(step):
+        """The record flush of superstep ``step`` (local to the launch):
+        the staged records go to their own row block of the HBM log."""
+        return pltpu.make_async_copy(
+            recbuf, log.at[pl.ds(step * rec_rows, rec_rows)], recsem.at[0])
 
-        wbuf[slot, 0] = v
-        wmeta[slot, 0] = q
-        wmeta[slot, 1] = h
-        pltpu.make_async_copy(wbuf.at[slot], paths.at[q, pl.ds(h, 1)],
-                              wsem.at[slot]).start()
-        wcnt[0] = c + 1
+    def flush_wait():
+        """Drain the one record flush in flight, if any — before the
+        staging rows are overwritten, and at the end of the launch."""
+        @pl.when(wcnt[0] == 1)
+        def _():
+            flush_copy(wcnt[1]).wait()
+            wcnt[0] = 0
 
     # ---- bring the launch-resident state into the output refs ----------
     def cp_w(i, _):
@@ -878,16 +898,6 @@ def fused_superstep_kernel(
         return 0
 
     jax.lax.fori_loop(0, W, cp_w, 0)
-
-    def cp_q(i, _):
-        done[i] = done_in[i]
-        if record_paths:
-            len_o[i] = len_in[i]
-        return 0
-
-    jax.lax.fori_loop(0, Q, cp_q, 0)
-    if not record_paths:
-        len_o[0] = len_in[0]
     for i in range(3):
         qctr[i] = qctr_in[i]
     for i in range(delay + 1):
@@ -897,7 +907,7 @@ def fused_superstep_kernel(
     stats[STAT["launches"]] = stats[STAT["launches"]] + 1
 
     # ---- one superstep (bit-identical to walk_engine._superstep) -------
-    def superstep(_s, carry):
+    def superstep(step, carry):
         head = qctr[0]
         tail = qctr[2]
         n_active = jax.lax.fori_loop(0, W, lambda i, a: a + act[i],
@@ -906,6 +916,16 @@ def fused_superstep_kernel(
 
         @pl.when(work)
         def _():
+            # -- prefetch the arrival-ring window refill may consume: at
+            # most W arrivals from `head`, in flight across the gathers.
+            qoff = head - head0
+            qcopies = [pltpu.make_async_copy(
+                qwin_ref.at[pl.ds(f * field_rows + qoff // LANES,
+                                  win_rows)], qbuf.at[f], qsem.at[f])
+                for f in range(len(WIN_FIELDS))]
+            for cp in qcopies:
+                cp.start()
+
             # -- per-lane stop draw + sampling uniforms (in-kernel RNG) --
             # The draw phase of the program: uniform/metapath consume one
             # uniform, alias two (counter layout exactly matches
@@ -941,7 +961,7 @@ def fused_superstep_kernel(
             jax.lax.fori_loop(0, W, lane_rng, 0)
 
             # -- Row Access: packed (addr, deg) DMA per lane, or the
-            # gather hierarchy (coalesce -> VMEM probe -> miss DMA) -----
+            # gather hierarchy (coalesce -> SMEM probe -> miss DMA) -----
             if use_cache:
                 _cached_row_access(W, num_vertices, cache, rp_ref,
                                    rpbuf, rpsem, vcur, act,
@@ -969,7 +989,7 @@ def fused_superstep_kernel(
                     kq0_scr, kq1_scr, cand_scr, got_scr, cache=cache)
             elif kind == "reservoir_n2v":
                 _reservoir_sample(
-                    W, num_vertices, num_edges, res_chunk, res_len,
+                    W, num_vertices, num_edges, res_chunk, res_rows,
                     inv_p, inv_q, max_degree, has_weights, k0, k1,
                     rp_ref, col_ref, wgt_ref,
                     colbuf, colsem, pairbuf, pairsem,
@@ -980,8 +1000,8 @@ def fused_superstep_kernel(
                     cand_scr, bkey_scr, ures_scr, fnd_scr, cache=cache)
             elif kind == "metapath":
                 _metapath_sample(
-                    W, num_vertices, num_edges, mp_sched, to_ref, col_ref,
-                    colbuf, colsem, pairbuf, pairsem,
+                    W, num_vertices, num_edges, mp_sched, to_width, to_ref,
+                    col_ref, colbuf, colsem, pairbuf, pairsem,
                     vcur, hop_o, u0_scr, addr_scr, deg_scr, idx_scr,
                     vnext_scr, cache=cache)
             else:
@@ -1024,7 +1044,11 @@ def fused_superstep_kernel(
                     colsem, num_edges, on_col, cache,
                     cache.col if cache is not None else None)
 
-            # -- terminate + advance + async path/done write-back --------
+            # -- terminate + advance; stage this superstep's records -----
+            # (the previous superstep's flush has overlapped the gathers
+            # above; drain it before its staging rows are rewritten).
+            flush_wait()
+
             def lane_update(i, acc):
                 steps_acc, term_acc = acc
                 A = act[i] == 1
@@ -1035,21 +1059,13 @@ def fused_superstep_kernel(
                 nh = jnp.where(adv, hop_o[i] + 1, hop_o[i])
                 term = stop | dead | (adv & (nh >= max_hops))
                 term_scr[i] = term.astype(jnp.int32)
-                q = qid_o[i]
+                rec("query", i, jnp.where(A, qid_o[i], -1))
+                rec("hop", i, jnp.where(adv, nh, -1))
+                rec("vertex", i, vnext_scr[i])
+                rec("done", i, (term & A).astype(jnp.int32))
                 vprev[i] = jnp.where(adv, vcur[i], vprev[i])
                 vcur[i] = jnp.where(adv, vnext_scr[i], vcur[i])
                 hop_o[i] = nh
-
-                if record_paths:
-                    @pl.when(adv)
-                    def _():
-                        len_o[q] = nh + 1
-                        path_write(q, nh, vnext_scr[i])
-
-                @pl.when(term & A)
-                def _():
-                    done[q] = 1
-
                 return (steps_acc + adv.astype(jnp.int32),
                         term_acc + (term & A).astype(jnp.int32))
 
@@ -1075,13 +1091,16 @@ def fused_superstep_kernel(
                                  jnp.minimum(hist[0] + depth, tail))
             qctr[1] = staged
 
-            # -- zero-bubble prefix-sum refill from the order ring -------
+            # -- zero-bubble prefix-sum refill from the ring window ------
+            for cp in qcopies:
+                cp.wait()
             if static_mode:
                 all_free = jax.lax.fori_loop(
                     0, W,
                     lambda i, a: a & ((act[i] == 0) | (term_scr[i] == 1)),
                     True)
             avail = jnp.maximum(staged - head, 0)
+            shift = jax.lax.rem(qoff, LANES)
 
             def lane_refill(i, acc):
                 rank, taken = acc
@@ -1089,21 +1108,24 @@ def fused_superstep_kernel(
                 if static_mode:
                     free = free & all_free
                 take = free & (rank < avail)
+                rec("new_query", i, -1)
+                rec("new_start", i, 0)
 
                 @pl.when(take)
                 def _():
-                    pos = jax.lax.rem(head + rank, Q)
-                    nq = qorder_ref[pos]
-                    start = qstart_ref[nq]
+                    w = shift + rank
+                    r = w // LANES
+                    c = jax.lax.rem(w, LANES)
+                    nq = qbuf[0, r, c]
+                    start = qbuf[1, r, c]
                     vcur[i] = start
                     vprev[i] = -1
                     qid_o[i] = nq
                     hop_o[i] = 0
                     act[i] = 1
-                    ep_o[i] = qepoch_ref[nq]
-                    if record_paths:
-                        len_o[nq] = 1
-                        path_write(nq, 0, start)
+                    ep_o[i] = qbuf[2, r, c]
+                    rec("new_query", i, nq)
+                    rec("new_start", i, start)
 
                 @pl.when((~take) & (term_scr[i] == 1))
                 def _():
@@ -1117,19 +1139,12 @@ def fused_superstep_kernel(
                 0, W, lane_refill, (jnp.int32(0), jnp.int32(0)))
             qctr[0] = head + n_taken
 
+            # -- stream this superstep's records out (async) -------------
+            flush_copy(step).start()
+            wcnt[0] = 1
+            wcnt[1] = step
+
         return carry
 
     jax.lax.fori_loop(0, ctl_ref[0], superstep, 0)
-
-    if record_paths:
-        # Drain the (at most two) in-flight path stores before the launch
-        # returns its state.
-        c = wcnt[0]
-        for back in (2, 1):
-            @pl.when(c >= back)
-            def _(back=back):
-                slot = jax.lax.rem(c - back, 2)
-                pltpu.make_async_copy(
-                    wbuf.at[slot],
-                    paths.at[wmeta[slot, 0], pl.ds(wmeta[slot, 1], 1)],
-                    wsem.at[slot]).wait()
+    flush_wait()

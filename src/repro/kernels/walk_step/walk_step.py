@@ -2,14 +2,17 @@
 (§V-B) as one kernel: Row Access → Sampling → Column Access.
 
 TPU adaptation of the asynchronous memory-access engine:
-  * ``row_ptr`` / ``col`` (and alias tables) live in HBM (`pl.ANY`); the
-    kernel issues **double-buffered async DMAs** per task — the copy for
+  * ``row_ptr`` / ``col`` (and alias tables) live in HBM (`pl.ANY`) as
+    128-lane rows (`kernels.common.graph_rows`, built once per run: the
+    chip's DMA engine moves whole rows, not single words); the kernel issues **double-buffered
+    async DMAs** per task — the copy for
     task *i+1* is in flight while task *i* is processed, which is exactly
     the paper's non-blocking outstanding-request scheme (scaled to the
     DMA depth Pallas exposes; the FPGA engine keeps 128 in flight, a TPU
     core hides latency with the same overlap via its DMA queues).
-  * Row access loads ``row_ptr[v]`` and ``row_ptr[v+1]`` in ONE 2-element
-    DMA (the paper's RP_entry packs address+degree in one word).
+  * Row access loads ``row_ptr[v]`` and ``row_ptr[v+1]`` in ONE DMA of
+    the two rows that can hold them (the paper's RP_entry packs
+    address+degree in one word).
   * Sampling arithmetic (uniform or alias) runs on scalars in SMEM between
     the two gather stages, so intermediates never round-trip to HBM.
   * Task words (v_curr, uniforms) are staged in SMEM — the analogue of the
@@ -27,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import ScheduleBuilder
+from repro.kernels.common import LANES, ScheduleBuilder
 
 
 def dma_schedule(kind: str = "uniform", n: int = 3):
@@ -50,17 +53,29 @@ def dma_schedule(kind: str = "uniform", n: int = 3):
     return b.ops
 
 
-def row_access_loop(n, v_fn, rp_ref, rpbuf, rpsem, num_vertices, on_result):
-    """Double-buffered 2-element DMA loop over lanes: rpbuf[slot] gets
-    (row_ptr[v], row_ptr[v+1]) for v = v_fn(i) — the paper's packed
-    RP_entry, with lane i+1's fetch in flight while lane i is consumed.
-    Calls on_result(i, addr, deg).  Shared with the fused superstep
-    kernel (`kernels/fused_superstep`)."""
+def _rows_copy(src_rows, e, nrows, buf, sem, slot):
+    """Async copy of the ``nrows`` 128-lane rows starting at the row that
+    holds flat word ``e`` of a row-tiled array (`kernels.common.graph_rows`)
+    into staging slot ``slot``."""
+    return pltpu.make_async_copy(src_rows.at[pl.ds(e // LANES, nrows)],
+                                 buf.at[slot], sem.at[slot])
+
+
+def _word(buf, slot, off):
+    """Word ``off`` (counted from the first staged row) of slot ``slot``."""
+    return buf[slot, off // LANES, off % LANES]
+
+
+def gather2_loop(n, f_fn, src_rows, buf, sem, on_result):
+    """Double-buffered packed-pair gather: ``buf[slot]`` (two rows) gets
+    the rows holding flat words ``f_fn(i)`` and ``f_fn(i) + 1`` of the
+    row-tiled ``src_rows`` — an RP_entry or a ``type_offsets[v, t:t+2]``
+    sub-segment bound — with item i+1's fetch in flight while item i is
+    consumed.  Calls on_result(i, first, second).  Shared with the fused
+    superstep kernel (`kernels/fused_superstep`)."""
 
     def copy(i, slot):
-        vv = jnp.clip(v_fn(i), 0, num_vertices - 1)
-        return pltpu.make_async_copy(rp_ref.at[pl.ds(vv, 2)],
-                                     rpbuf.at[slot], rpsem.at[slot])
+        return _rows_copy(src_rows, f_fn(i), 2, buf, sem, slot)
 
     def body(i, _):
         slot = jax.lax.rem(i, 2)
@@ -70,25 +85,38 @@ def row_access_loop(n, v_fn, rp_ref, rpbuf, rpsem, num_vertices, on_result):
             copy(i + 1, jax.lax.rem(i + 1, 2)).start()
 
         copy(i, slot).wait()
-        addr = rpbuf[slot, 0]
-        deg = rpbuf[slot, 1] - rpbuf[slot, 0]
-        on_result(i, addr, deg)
+        off = jax.lax.rem(f_fn(i), LANES)
+        on_result(i, _word(buf, slot, off), _word(buf, slot, off + 1))
         return 0
 
     copy(0, 0).start()
     jax.lax.fori_loop(0, n, body, 0, unroll=False)
 
 
-def gather2_loop(n, src_fn, buf, sem, on_result):
-    """Double-buffered 2-element DMA gather: buf[slot] gets the packed
-    word pair at ``src_fn(i)`` (a 2-element ref slice — an RP_entry or a
-    ``type_offsets[v, t:t+2]`` sub-segment bound), with item i+1's fetch
-    in flight while item i is consumed.  Calls on_result(i, first,
-    second).  Shared with the fused superstep kernel
-    (`kernels/fused_superstep`)."""
+def row_access_loop(n, v_fn, rp_rows, rpbuf, rpsem, num_vertices,
+                    on_result):
+    """Double-buffered RP_entry loop over lanes: the pair (row_ptr[v],
+    row_ptr[v+1]) for v = v_fn(i) — the paper's packed RP_entry, with lane
+    i+1's fetch in flight while lane i is consumed.  Calls
+    on_result(i, addr, deg).  Shared with the fused superstep kernel."""
+
+    def f(i):
+        return jnp.clip(v_fn(i), 0, num_vertices - 1)
+
+    gather2_loop(n, f, rp_rows, rpbuf, rpsem,
+                 lambda i, lo, hi: on_result(i, lo, hi - lo))
+
+
+def gather1_loop(n, e_fn, src_rows, buf, sem, num_entries, on_result):
+    """Double-buffered one-word gather: buf[slot] gets the row holding
+    ``src[e_fn(i)]`` of the row-tiled ``src_rows``.  Shared with the fused
+    superstep kernel."""
+
+    def e_of(i):
+        return jnp.clip(e_fn(i), 0, num_entries - 1)
 
     def copy(i, slot):
-        return pltpu.make_async_copy(src_fn(i), buf.at[slot], sem.at[slot])
+        return _rows_copy(src_rows, e_of(i), 1, buf, sem, slot)
 
     def body(i, _):
         slot = jax.lax.rem(i, 2)
@@ -98,42 +126,18 @@ def gather2_loop(n, src_fn, buf, sem, on_result):
             copy(i + 1, jax.lax.rem(i + 1, 2)).start()
 
         copy(i, slot).wait()
-        on_result(i, buf[slot, 0], buf[slot, 1])
+        on_result(i, _word(buf, slot, jax.lax.rem(e_of(i), LANES)))
         return 0
 
     copy(0, 0).start()
     jax.lax.fori_loop(0, n, body, 0, unroll=False)
 
 
-def gather1_loop(n, e_fn, src_ref, buf, sem, num_entries, on_result):
-    """Double-buffered 1-element DMA gather: buf[slot] = src[e_fn(i)].
-    Shared with the fused superstep kernel."""
-
-    def copy(i, slot):
-        e = jnp.clip(e_fn(i), 0, num_entries - 1)
-        return pltpu.make_async_copy(src_ref.at[pl.ds(e, 1)],
-                                     buf.at[slot], sem.at[slot])
-
-    def body(i, _):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n)
-        def _():
-            copy(i + 1, jax.lax.rem(i + 1, 2)).start()
-
-        copy(i, slot).wait()
-        on_result(i, buf[slot, 0])
-        return 0
-
-    copy(0, 0).start()
-    jax.lax.fori_loop(0, n, body, 0, unroll=False)
-
-
-def cached_gather1_loop(n, e_fn, src_ref, buf, sem, num_entries, on_result,
+def cached_gather1_loop(n, e_fn, src_rows, buf, sem, num_entries, on_result,
                         *, cache_ref=None, cache_len=1, cache_e_fn=None,
                         hit_fn=None):
     """Skew-aware variant of :func:`gather1_loop`: items whose lane holds
-    a hot vertex (``hit_fn(i)``) are served straight from the VMEM cache
+    a hot vertex (``hit_fn(i)``) are served straight from the SMEM cache
     block ``cache_ref`` (no copy, no wait — the same bytes at
     ``cache_e_fn(i)``), while misses run the standard double-buffered HBM
     loop.  Both the prefetch for item i+1 and the wait for item i are
@@ -142,7 +146,7 @@ def cached_gather1_loop(n, e_fn, src_ref, buf, sem, num_entries, on_result,
     packs verbatim CSR slices.  With ``hit_fn=None`` (cache off) this IS
     `gather1_loop` — the uncached kernel trace is unchanged."""
     if hit_fn is None or cache_ref is None:
-        return gather1_loop(n, e_fn, src_ref, buf, sem, num_entries,
+        return gather1_loop(n, e_fn, src_rows, buf, sem, num_entries,
                             on_result)
     cache_e_fn = cache_e_fn or e_fn
 
@@ -151,10 +155,11 @@ def cached_gather1_loop(n, e_fn, src_ref, buf, sem, num_entries, on_result,
         # already false there.
         return hit_fn(jnp.minimum(i, n - 1))
 
+    def e_of(i):
+        return jnp.clip(e_fn(i), 0, num_entries - 1)
+
     def copy(i, slot):
-        e = jnp.clip(e_fn(i), 0, num_entries - 1)
-        return pltpu.make_async_copy(src_ref.at[pl.ds(e, 1)],
-                                     buf.at[slot], sem.at[slot])
+        return _rows_copy(src_rows, e_of(i), 1, buf, sem, slot)
 
     def body(i, _):
         slot = jax.lax.rem(i, 2)
@@ -171,7 +176,8 @@ def cached_gather1_loop(n, e_fn, src_ref, buf, sem, num_entries, on_result,
         ce = jnp.clip(cache_e_fn(i), 0, cache_len - 1)
         # Hit lanes never started a copy: buf holds a stale value the
         # where() discards.
-        on_result(i, jnp.where(h, cache_ref[ce], buf[slot, 0]))
+        on_result(i, jnp.where(h, cache_ref[ce],
+                               _word(buf, slot, jax.lax.rem(e_of(i), LANES))))
         return 0
 
     @pl.when(jnp.logical_not(hit_fn(0)))
@@ -181,18 +187,18 @@ def cached_gather1_loop(n, e_fn, src_ref, buf, sem, num_entries, on_result,
     jax.lax.fori_loop(0, n, body, 0, unroll=False)
 
 
-def cached_gather2_loop(n, src_fn, buf, sem, on_result, *, hit_fn=None,
-                        hit_pair_fn=None):
+def cached_gather2_loop(n, f_fn, src_rows, buf, sem, on_result, *,
+                        hit_fn=None, hit_pair_fn=None):
     """Skew-aware variant of :func:`gather2_loop`: hit items take their
-    word pair from ``hit_pair_fn(i)`` (a VMEM cache read) instead of the
+    word pair from ``hit_pair_fn(i)`` (an SMEM cache read) instead of the
     DMA staging buffer, with the same miss-predicated prefetch/wait
     structure as :func:`cached_gather1_loop`.  ``hit_fn=None`` falls back
     to the plain loop."""
     if hit_fn is None or hit_pair_fn is None:
-        return gather2_loop(n, src_fn, buf, sem, on_result)
+        return gather2_loop(n, f_fn, src_rows, buf, sem, on_result)
 
     def copy(i, slot):
-        return pltpu.make_async_copy(src_fn(i), buf.at[slot], sem.at[slot])
+        return _rows_copy(src_rows, f_fn(i), 2, buf, sem, slot)
 
     def body(i, _):
         slot = jax.lax.rem(i, 2)
@@ -208,8 +214,9 @@ def cached_gather2_loop(n, src_fn, buf, sem, on_result, *, hit_fn=None,
             copy(i, slot).wait()
 
         ca, cb = hit_pair_fn(i)
-        on_result(i, jnp.where(h, ca, buf[slot, 0]),
-                  jnp.where(h, cb, buf[slot, 1]))
+        off = jax.lax.rem(f_fn(i), LANES)
+        on_result(i, jnp.where(h, ca, _word(buf, slot, off)),
+                  jnp.where(h, cb, _word(buf, slot, off + 1)))
         return 0
 
     @pl.when(jnp.logical_not(hit_fn(0)))
@@ -288,15 +295,15 @@ def _smem_tile(tile):
     return pl.BlockSpec((tile,), lambda t: (t,), memory_space=pltpu.SMEM)
 
 
-def walk_step_uniform(v_curr, u_col, row_ptr, col, *, tile: int = 256,
+def walk_step_uniform(v_curr, u_col, rows, *, tile: int = 1024,
                       interpret: bool = True):
-    """pallas_call wrapper: (v_next, deg) for a batch of walker lanes."""
+    """pallas_call wrapper: (v_next, deg) for a batch of walker lanes;
+    ``rows`` is the graph's `kernels.common.GraphRows`."""
     W = v_curr.shape[0]
     tile = min(tile, W)
     assert W % tile == 0, (W, tile)
-    nv = row_ptr.shape[0] - 1
-    ne = col.shape[0]
-    kernel = functools.partial(walk_step_uniform_kernel, nv, ne)
+    kernel = functools.partial(walk_step_uniform_kernel, rows.num_vertices,
+                               rows.num_edges)
     return pl.pallas_call(
         kernel,
         grid=(W // tile,),
@@ -308,22 +315,21 @@ def walk_step_uniform(v_curr, u_col, row_ptr, col, *, tile: int = 256,
                    jax.ShapeDtypeStruct((W,), jnp.int32)],
         scratch_shapes=[pltpu.SMEM((tile,), jnp.int32),
                         pltpu.SMEM((tile,), jnp.int32),
-                        pltpu.SMEM((2, 2), jnp.int32),
-                        pltpu.SMEM((2, 1), jnp.int32),
+                        pltpu.SMEM((2, 2, LANES), jnp.int32),
+                        pltpu.SMEM((2, 1, LANES), jnp.int32),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
-    )(v_curr, u_col, row_ptr, col)
+    )(v_curr, u_col, rows.row_ptr, rows.col)
 
 
-def walk_step_alias(v_curr, u_col, u_acc, row_ptr, col, alias_prob, alias_idx,
-                    *, tile: int = 256, interpret: bool = True):
+def walk_step_alias(v_curr, u_col, u_acc, rows, *, tile: int = 1024,
+                    interpret: bool = True):
     W = v_curr.shape[0]
     tile = min(tile, W)
     assert W % tile == 0, (W, tile)
-    nv = row_ptr.shape[0] - 1
-    ne = col.shape[0]
-    kernel = functools.partial(walk_step_alias_kernel, nv, ne)
+    kernel = functools.partial(walk_step_alias_kernel, rows.num_vertices,
+                               rows.num_edges)
     return pl.pallas_call(
         kernel,
         grid=(W // tile,),
@@ -338,13 +344,14 @@ def walk_step_alias(v_curr, u_col, u_acc, row_ptr, col, alias_prob, alias_idx,
         scratch_shapes=[pltpu.SMEM((tile,), jnp.int32),
                         pltpu.SMEM((tile,), jnp.int32),
                         pltpu.SMEM((tile,), jnp.int32),
-                        pltpu.SMEM((2, 2), jnp.int32),
-                        pltpu.SMEM((2, 1), jnp.float32),
-                        pltpu.SMEM((2, 1), jnp.int32),
-                        pltpu.SMEM((2, 1), jnp.int32),
+                        pltpu.SMEM((2, 2, LANES), jnp.int32),
+                        pltpu.SMEM((2, 1, LANES), jnp.float32),
+                        pltpu.SMEM((2, 1, LANES), jnp.int32),
+                        pltpu.SMEM((2, 1, LANES), jnp.int32),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
-    )(v_curr, u_col, u_acc, row_ptr, col, alias_prob, alias_idx)
+    )(v_curr, u_col, u_acc, rows.row_ptr, rows.col, rows.alias_prob,
+      rows.alias_idx)

@@ -189,6 +189,8 @@ def main():
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]

@@ -37,6 +37,8 @@ def main():
     ap.add_argument("--out", default="experiments/perf")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from repro.launch.dryrun import run_cell
     overrides = {}

@@ -107,6 +107,8 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=8)
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     mod = get_arch(args.arch)
     assert mod.FAMILY == "lm", "serving is for LM archs"

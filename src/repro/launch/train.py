@@ -79,6 +79,8 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     mod = get_arch(args.arch)
     cfg = mod.SMOKE if args.smoke else mod.FULL

@@ -4,7 +4,8 @@ unified walker API (`repro.walker.compile`).
   PYTHONPATH=src python -m repro.launch.walk --algo deepwalk --dataset WG \
       --queries 2000 --slots 1024
   PYTHONPATH=src python -m repro.launch.walk --algo node2vec --backend sharded \
-      --devices 8 ...   (needs XLA_FLAGS=--xla_force_host_platform_device_count=8)
+      ...   (one partition per device present; on the CPU set
+             XLA_FLAGS=--xla_force_host_platform_device_count=N first)
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import time
 import numpy as np
 
 from repro import walker
+from repro.compile_cache import use_compile_cache
 from repro.configs.ridgewalker import ALGORITHMS, QUERY_LENGTH
 from repro.core.scheduler import analyze_run
 from repro.graph import make_dataset
@@ -38,10 +40,13 @@ def main():
                     choices=list(walker.BACKENDS))
     ap.add_argument("--distributed", action="store_true",
                     help="alias for --backend sharded")
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="sharded only: partitions, one per device "
+                         "(default: every device present)")
     ap.add_argument("--record-paths", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = ALGORITHMS[args.algo]
     program = walker.WalkProgram(spec=spec, max_hops=args.max_hops,

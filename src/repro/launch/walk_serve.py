@@ -47,6 +47,8 @@ def main():
     ap.add_argument("--injection-delay", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     spec = ALGORITHMS[args.algo]
     weighted = spec.kind in ("alias", "reservoir_n2v")

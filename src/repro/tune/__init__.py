@@ -24,9 +24,8 @@ from repro.tune.cache import (GraphSignature, TuningCache, cache_key,
                               default_cache_path, graph_signature,
                               workload_bucket)
 from repro.tune.measure import InjectedMeasurer, Measurer, WalkMeasurer
-from repro.tune.model import (DEFAULT_COEFFS, CostCoeffs,
-                              adaptive_chunk_gate, bytes_per_hop,
-                              expected_walk_len, fit, live_max_degree,
+from repro.tune.model import (DEFAULT_COEFFS, CostCoeffs, bytes_per_hop,
+                              expected_walk_len, fit, lane_degree,
                               predict_us, prune)
 from repro.tune.space import (Candidate, Knob, default_candidate,
                               enumerate_candidates, knobs_for)
@@ -36,8 +35,8 @@ __all__ = [
     "GraphSignature", "TuningCache", "cache_key", "default_cache_path",
     "graph_signature", "workload_bucket",
     "Measurer", "InjectedMeasurer", "WalkMeasurer",
-    "CostCoeffs", "DEFAULT_COEFFS", "adaptive_chunk_gate", "bytes_per_hop",
-    "expected_walk_len", "fit", "live_max_degree", "predict_us", "prune",
+    "CostCoeffs", "DEFAULT_COEFFS", "bytes_per_hop", "expected_walk_len",
+    "fit", "lane_degree", "predict_us", "prune",
     "Candidate", "Knob", "default_candidate", "enumerate_candidates",
     "knobs_for",
     "TuneResult", "autotune", "needs_resolution", "resolve",

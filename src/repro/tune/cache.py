@@ -5,8 +5,8 @@ workload)* — not constants — so the cache key folds in:
 
   * the **graph signature**: n, m, max degree, payload flags, and two
     degree-quantile ladders (plain and degree-weighted; the weighted
-    ladder is what predicts the live-lane max degree of a W-lane pool,
-    see `repro.tune.model.live_max_degree`);
+    ladder is what predicts the degree a walking lane sits on, see
+    `repro.tune.model.lane_degree`);
   * the **sampler kind** (each kind has its own DMA schedule and
     bytes/hop profile);
   * the **machine axes**: backend, ``step_impl``, device kind, and the

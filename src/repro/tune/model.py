@@ -16,13 +16,12 @@ coefficients ``(a, b, c, d)`` form a :class:`CostCoeffs`; they can be
 to rank and prune the candidate grid before any timing
 (:func:`prune`) — the roofline-model pruning of the tuner.
 
-The model also owns the **degree-adaptive reservoir gate**: the live
-max degree of a W-lane pool on a skewed graph concentrates around the
-degree-weighted quantile at ``q = 0.5**(1/W)`` (each of W roughly
-independent lanes sits below d with probability F_w(d)), so the
-expected chunk-loop trip count of the adaptive scan is predictable from
-the graph signature alone — no timing needed to decide the
-``adaptive_chunks="auto"`` sentinel.
+The reservoir chunk-trip count comes from the graph signature too: a
+walking lane sits on a vertex with probability proportional to its
+degree, so the typical lane scans ``ceil(d/chunk)`` chunks at the
+degree-weighted median degree ``d`` — both the jnp scan (which scores
+the live lanes' degree sum) and the fused kernel (``ceil(deg/chunk)``
+trips per lane) follow each lane's own degree.
 
 No wall-clock here: everything is arithmetic over the
 :class:`~repro.tune.cache.GraphSignature` and the phase program's
@@ -42,17 +41,22 @@ from repro.core.samplers import es_num_chunks
 from repro.tune.cache import PLAIN_QS, WEIGHTED_QS, GraphSignature
 from repro.tune.space import Candidate
 
-# Bytes moved per `start` op of the declarative DMA schedule, by buffer.
-# Scalar probes are 4-byte words; RP_entry / (lo, hi) pair probes are two
-# words; the reservoir chunk stages copy a whole CH-wide chunk of columns
-# or weights per start.
+# Bytes the sampler needs per `start` op of the declarative DMA
+# schedule, by buffer (per lane for one hop).  Scalar probes are 4-byte
+# words; RP_entry / (lo, hi) pair probes are two words; the reservoir
+# chunk stages a whole CH-wide chunk of columns or weights per start; the
+# superstep record is six words per lane and the arrival-ring window
+# three.  These are the words the walk consumes, not what the DMA engine
+# moves: on the chip each gather copies whole 128-word rows
+# (`kernels.common.as_rows`), which this model does not price.
 _BUF_WORD_BYTES = {
     "rpbuf": 8,     # RP_entry: (row_ptr[v], row_ptr[v+1])
     "pairbuf": 8,   # v_prev RP_entry / typed sub-segment bounds
     "colbuf": 4,    # one column probe
     "probbuf": 4,   # alias probability probe
     "aliasbuf": 4,  # alias index probe
-    "wbuf": 8,      # path write-back record (qid, vertex)
+    "wbuf": 24,     # superstep record (query, hop, vertex, done, refill)
+    "qbuf": 12,     # arrival-ring entry (slot id, start, epoch)
 }
 
 
@@ -85,19 +89,18 @@ def expected_walk_len(program) -> float:
 
 @functools.lru_cache(maxsize=256)
 def _schedule_bytes(kind: str, rounds: int, bisect_iters: int, chunks: int,
-                    reservoir_chunk: int, record_paths: bool,
-                    cached: bool = False) -> float:
+                    reservoir_chunk: int, cached: bool = False) -> float:
     """Per-lane bytes of one hop, summed over the kind's DMA schedule.
 
     ``cached=True`` prices the fully-hit representative superstep of the
     gather hierarchy: only the HBM copies the cache cannot absorb remain
-    (v_prev-keyed probes, path write-back) — VMEM-tier reads move no HBM
+    (v_prev-keyed probes, record flush) — on-chip cache reads move no HBM
     bytes and are skipped with the rest of the non-``start`` ops.
     """
     from repro.kernels.fused_superstep.fused_superstep import dma_schedule
     ops = dma_schedule(kind, lanes=1, rounds=rounds,
                        bisect_iters=bisect_iters, chunks=chunks,
-                       records=1, record_paths=record_paths, cached=cached)
+                       records=1, cached=cached)
     total = 0.0
     for op in ops:
         if op.kind != "start":
@@ -111,12 +114,11 @@ def _schedule_bytes(kind: str, rounds: int, bisect_iters: int, chunks: int,
 
 def bytes_per_hop(spec, sig: GraphSignature,
                   chunk_trips: Optional[int] = None,
-                  record_paths: bool = False,
                   cached: bool = False) -> float:
     """Per-lane bytes gathered per hop for ``spec`` on a ``sig`` graph.
 
-    ``chunk_trips`` overrides the reservoir chunk-loop trip count (the
-    adaptive scan runs fewer trips than the static
+    ``chunk_trips`` overrides the reservoir chunk-loop trip count (a
+    lane scans ``ceil(deg/CH)`` chunks, fewer than the static
     ``es_num_chunks(max_degree, CH)`` bound).  ``cached=True`` prices a
     cache-hit hop (residual HBM traffic only); blend the two with
     :func:`predicted_hit_rate` for the effective per-hop bytes.
@@ -129,7 +131,7 @@ def bytes_per_hop(spec, sig: GraphSignature,
                  else es_num_chunks(sig.max_degree, spec.reservoir_chunk))
     return _schedule_bytes(spec.kind, int(spec.rejection_rounds), bisect,
                            max(1, trips), int(spec.reservoir_chunk),
-                           bool(record_paths), bool(cached))
+                           bool(cached))
 
 
 @functools.lru_cache(maxsize=64)
@@ -180,53 +182,22 @@ def predicted_hit_rate(sig: GraphSignature, budget_bytes: int,
     return float(min(max(best, 0.0), 1.0))
 
 
-# ------------------------------------------------------------------ gate
-
-
-def live_max_degree(sig: GraphSignature, num_slots: int) -> int:
-    """Predicted max degree among ``num_slots`` live lanes.
-
-    A walking lane occupies a vertex with probability proportional to
-    its degree (stationary distribution of an undirected random walk),
-    so the max over W lanes concentrates at the degree-weighted quantile
-    ``q = 0.5**(1/W)`` — interpolated over the signature's stored
-    weighted-quantile ladder.
-    """
-    w = max(int(num_slots), 1)
-    q = 0.5 ** (1.0 / w)
-    qs = np.asarray(WEIGHTED_QS)
-    vals = np.asarray(sig.deg_wq, dtype=np.float64)
-    return int(round(float(np.interp(q, qs, vals))))
-
-
-def adaptive_chunk_gate(sig: GraphSignature, num_slots: int, chunk: int,
-                        margin: float = 0.75) -> bool:
-    """Should the degree-adaptive reservoir scan be on for this graph?
-
-    The adaptive scan bounds the E-S chunk loop by the live lanes' max
-    degree instead of the graph's ``max_degree``; its win is the trip
-    ratio, its cost a dynamic loop bound.  Gate it on only when the
-    predicted trips fall below ``margin`` of the static bound — on
-    balanced graphs the ratio is ~1 and the gate keeps the fixed scan,
-    so the adaptive path can no longer lose to it.
-    """
-    ch = max(int(chunk), 1)
-    t_live = -(-live_max_degree(sig, num_slots) // ch)
-    t_fixed = es_num_chunks(sig.max_degree, ch)
-    return max(1, t_live) <= margin * t_fixed
-
-
 # ----------------------------------------------------------- prediction
 
 
-def _reservoir_trips(spec, sig: GraphSignature, num_slots: int,
-                     adaptive) -> Optional[int]:
+def lane_degree(sig: GraphSignature) -> int:
+    """Degree of a typical walking lane's vertex: the degree-weighted
+    median (a lane occupies a vertex with probability proportional to
+    its degree), interpolated over the signature's weighted ladder."""
+    qs = np.asarray(WEIGHTED_QS)
+    vals = np.asarray(sig.deg_wq, dtype=np.float64)
+    return int(round(float(np.interp(0.5, qs, vals))))
+
+
+def _reservoir_trips(spec, sig: GraphSignature) -> Optional[int]:
     if spec.kind != "reservoir_n2v":
         return None
-    if adaptive:
-        live = live_max_degree(sig, num_slots)
-        return max(1, -(-live // max(int(spec.reservoir_chunk), 1)))
-    return es_num_chunks(sig.max_degree, spec.reservoir_chunk)
+    return max(1, -(-lane_degree(sig) // max(int(spec.reservoir_chunk), 1)))
 
 
 def features(program, execution, sig: GraphSignature,
@@ -238,20 +209,15 @@ def features(program, execution, sig: GraphSignature,
     length = expected_walk_len(program)
     q = max(int(num_queries), 1)
     supersteps = max(length, math.ceil(q * length / max(w, 1)))
-    adaptive = spec.adaptive_chunks
-    if adaptive == "auto":
-        adaptive = adaptive_chunk_gate(sig, w, spec.reservoir_chunk)
-    trips = _reservoir_trips(spec, sig, w, adaptive)
-    b = bytes_per_hop(spec, sig, chunk_trips=trips,
-                      record_paths=ex.record_paths)
+    trips = _reservoir_trips(spec, sig)
+    b = bytes_per_hop(spec, sig, chunk_trips=trips)
     cb = getattr(ex, "cache_budget", 0)
     if ex.step_impl == "fused" and isinstance(cb, int) and cb > 0:
         # Gather hierarchy: a hit hop moves only the residual HBM bytes
         # the cache cannot absorb, so the effective per-hop traffic is
         # the hit-rate blend of the two schedules.
         h = predicted_hit_rate(sig, cb, _spec_payloads(spec))
-        b_hit = bytes_per_hop(spec, sig, chunk_trips=trips,
-                              record_paths=ex.record_paths, cached=True)
+        b_hit = bytes_per_hop(spec, sig, chunk_trips=trips, cached=True)
         b = (1.0 - h) * b + h * b_hit
     if ex.step_impl == "fused":
         launches = math.ceil(supersteps / max(int(ex.hops_per_launch), 1))

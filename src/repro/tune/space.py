@@ -11,7 +11,7 @@ is enforced for free: enumeration simply drops assignments whose
 Knobs are split by what they may change:
 
   * **path-preserving** knobs (``num_slots``, ``hops_per_launch``,
-    ``queue_depth_factor``, ``adaptive_chunks``) are pure machine knobs
+    ``queue_depth_factor``, ``cache_budget``) are pure machine knobs
     — sampled walks are bit-identical for any value (paper §V-A);
   * **resampling** knobs (``reservoir_chunk``) change which walks are
     drawn, because the E-S reservoir partitions its uniforms per chunk
@@ -30,7 +30,7 @@ from typing import Dict, Optional, Sequence, Tuple
 EXEC_KNOBS = ("num_slots", "hops_per_launch", "queue_depth_factor",
               "cache_budget")
 # Sampler-spec-level knobs.
-SPEC_KNOBS = ("reservoir_chunk", "adaptive_chunks")
+SPEC_KNOBS = ("reservoir_chunk",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,12 +59,11 @@ def knobs_for(program, execution, backend: str = "single") -> Tuple[Knob, ...]:
         knobs.append(Knob("hops_per_launch", (2, 4, 8, 16, 32, 64),
                           "execution"))
         # Hot-vertex cache byte budget (0 = off).  Path-preserving by
-        # construction: hits read the same bytes from VMEM instead of
+        # construction: hits read the same bytes from SMEM instead of
         # HBM, so the sampled walks cannot change.
         knobs.append(Knob("cache_budget", (0, 1 << 14, 1 << 16, 1 << 18),
                           "execution"))
     if program.spec.kind == "reservoir_n2v":
-        knobs.append(Knob("adaptive_chunks", (True, False), "spec"))
         knobs.append(Knob("reservoir_chunk", (16, 32, 64, 128, 256), "spec",
                           path_preserving=False))
     return tuple(knobs)
@@ -123,21 +122,17 @@ def default_candidate(program, execution,
         if k.target == "execution":
             vals[k.name] = getattr(resolved, k.name)
         else:
-            v = getattr(program.spec, k.name)
-            if k.name == "adaptive_chunks" and v == "auto":
-                v = True  # legacy default before gate resolution
-            vals[k.name] = v
+            vals[k.name] = getattr(program.spec, k.name)
     return Candidate.of(**vals)
 
 
 def enumerate_candidates(program, execution, backend: str = "single",
                          include_resampling: bool = False,
-                         only: Optional[Sequence[str]] = None,
-                         exclude: Sequence[str] = ()) -> Tuple[Candidate, ...]:
+                         only: Optional[Sequence[str]] = None
+                         ) -> Tuple[Candidate, ...]:
     """Every valid knob assignment for this (program, execution, backend).
 
-    Knobs not enumerated (filtered by ``only``/``exclude``/
-    ``include_resampling``) are pinned to their default-candidate value,
+    Knobs not enumerated (filtered by ``only``/``include_resampling``) are pinned to their default-candidate value,
     so every returned candidate is a *complete* assignment over the
     applicable knob set.  Assignments rejected by the config dataclasses'
     validation are dropped.  The default candidate is always included.
@@ -149,8 +144,6 @@ def enumerate_candidates(program, execution, backend: str = "single",
         if not include_resampling and not k.path_preserving:
             continue
         if only is not None and k.name not in only:
-            continue
-        if k.name in exclude:
             continue
         active.append(k)
     out = []

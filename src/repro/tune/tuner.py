@@ -3,8 +3,8 @@
 Two entry points:
 
 * :func:`resolve` — what ``walker/compile.py`` calls when an
-  ``ExecutionConfig`` carries ``"auto"`` sentinels (or a reservoir spec
-  carries ``adaptive_chunks="auto"``).  **Never times anything**: it
+  ``ExecutionConfig`` carries ``"auto"`` sentinels.  **Never times
+  anything**: it
   answers from the tuning cache, falling back to the analytical model
   (`repro.tune.model`) on a miss — so compiling a Walker stays
   deterministic and lint-clean.  Populate the cache with measured
@@ -45,10 +45,7 @@ def _interpret_mode() -> bool:
 
 def needs_resolution(program, execution) -> bool:
     """Does this (program, execution) carry any unresolved sentinel?"""
-    if getattr(execution, "has_auto", False):
-        return True
-    return (program.spec.kind == "reservoir_n2v"
-            and program.spec.adaptive_chunks == "auto")
+    return bool(getattr(execution, "has_auto", False))
 
 
 @dataclasses.dataclass
@@ -170,24 +167,12 @@ def autotune(graph, program, execution=None, backend: str = "single", *,
 
     default = _complete({}, program, execution, backend)
     if measurer is None:
-        # Model-only: the adaptive-reservoir axis is decided by the skew
-        # gate, not the byte model (the model cannot see the dynamic
-        # loop-bound overhead, so it would always prefer adaptive).
         cands = enumerate_candidates(program, execution, backend,
-                                     include_resampling=include_resampling,
-                                     exclude=("adaptive_chunks",))
+                                     include_resampling=include_resampling)
         preds = _model.predictions(program, execution, sig, num_queries,
                                    cands, base_coeffs)
         chosen = min(cands, key=lambda c: (preds[c], c != default))
-        gate = {}
-        if any(k.name == "adaptive_chunks"
-               for k in knobs_for(program, execution, backend)):
-            gate["adaptive_chunks"] = _model.adaptive_chunk_gate(
-                sig, int(chosen.get("num_slots")),
-                int(chosen.get("reservoir_chunk",
-                               program.spec.reservoir_chunk)))
-        chosen = _complete({**chosen.to_dict(), **gate}, program, execution,
-                           backend)
+        chosen = _complete(chosen.to_dict(), program, execution, backend)
         measured: Dict[Candidate, float] = {}
         fitted = None
         source = "model"
@@ -240,7 +225,7 @@ def resolve(program, execution, graph, backend: str = "single",
     """Resolve every ``"auto"`` sentinel to a concrete value.
 
     Cache hit -> the committed tuned value; miss -> analytical-model
-    argmin (and the skew gate for ``adaptive_chunks``).  No wall-clock
+    argmin.  No wall-clock
     on any path, so Walker compilation stays deterministic; run
     ``python -m repro.tune`` to fill the cache with measured entries.
     Returns the concrete ``(program, execution)`` pair.
@@ -262,8 +247,7 @@ def resolve(program, execution, graph, backend: str = "single",
     missing = [n for n in auto_names if n not in chosen]
     if missing:
         cands = enumerate_candidates(program, execution, backend,
-                                     only=missing,
-                                     exclude=("adaptive_chunks",))
+                                     only=missing)
         nq = num_queries or max(int(sig.num_vertices), 1)
         preds = _model.predictions(program, execution, sig, nq, cands)
         best = min(cands, key=lambda c: preds[c])
@@ -272,15 +256,4 @@ def resolve(program, execution, graph, backend: str = "single",
     ex2 = execution.resolved(**{k: v for k, v in chosen.items()
                                 if k in EXEC_KNOBS})
 
-    prog2 = program
-    spec = program.spec
-    if spec.kind == "reservoir_n2v" and spec.adaptive_chunks == "auto":
-        if "adaptive_chunks" in cached:
-            adaptive = bool(cached["adaptive_chunks"])
-        else:
-            adaptive = _model.adaptive_chunk_gate(sig, int(ex2.num_slots),
-                                                  int(spec.reservoir_chunk))
-        prog2 = dataclasses.replace(
-            program, spec=dataclasses.replace(spec,
-                                              adaptive_chunks=adaptive))
-    return prog2, ex2
+    return program, ex2

@@ -37,7 +37,8 @@ from repro.core.distributed import (DistLogs, assemble_paths,
                                     init_dist_stream_state,
                                     inject_stream_queries,
                                     make_distributed_engine,
-                                    make_sharded_stream_engine, shard_starts)
+                                    make_sharded_stream_engine,
+                                    mesh_devices, shard_starts)
 from repro.core.tasks import WalkResult, WalkStats
 from repro.core.walk_engine import (StreamState, build_engine,
                                     init_stream_state, inject_queries,
@@ -94,6 +95,9 @@ class Walker:
         self._resolved = {}         # (sig, workload) -> (program, execution)
         self._dist_cache = {}       # sharded runners keyed by graph shape
         self._emb_cache = {}        # train_embeddings jitted pieces
+        #: Per-device WalkStats of the last sharded ``run`` (one entry per
+        #: device in every field); ``run`` itself reports their totals.
+        self.last_device_stats: Optional[WalkStats] = None
 
     # ----------------------------------------------------------- internals
 
@@ -159,8 +163,8 @@ class Walker:
             cfg = execution.dist_config(program, pg.num_devices)
             mesh = self._mesh
             if mesh is None:
-                devs = np.array(jax.devices()[: pg.num_devices])
-                mesh = jax.sharding.Mesh(devs, (cfg.axis_name,))
+                mesh = jax.sharding.Mesh(mesh_devices(pg.num_devices),
+                                         (cfg.axis_name,))
             self._dist_cache[key] = (
                 make_distributed_engine(pg, program.spec, cfg, mesh), cfg)
         return self._dist_cache[key]
@@ -200,6 +204,7 @@ class Walker:
         base_key = task_rng.stream_key(seed)
         log_q, log_h, log_v, cursor, stats = run(
             pg, jnp.asarray(starts_sh), jnp.asarray(qcount), base_key)
+        self.last_device_stats = WalkStats(*stats)
         # Devices run the lockstep superstep loop the same number of times:
         # supersteps/launches are global clocks (max), the rest is additive.
         total = WalkStats(*(
@@ -253,8 +258,8 @@ class Walker:
         cfg = execution.dist_config(program, pg.num_devices)
         mesh = self._mesh
         if mesh is None:
-            devs = np.array(jax.devices()[: pg.num_devices])
-            mesh = jax.sharding.Mesh(devs, (cfg.axis_name,))
+            mesh = jax.sharding.Mesh(mesh_devices(pg.num_devices),
+                                     (cfg.axis_name,))
         return ShardedWalkStream(program, cfg, pg, mesh, capacity, seed)
 
     # ------------------------------------------------------------- service

@@ -60,7 +60,7 @@ class ExecutionConfig:
                         launch (the k of the O(k·state) → O(state) host-
                         traffic reduction; ``stats.launches`` exposes the
                         realized fusion factor).
-      cache_budget:     ``fused`` only — byte budget of the VMEM
+      cache_budget:     ``fused`` only — byte budget of the SMEM
                         hot-vertex adjacency cache (0 disables it).  The
                         top-H highest-degree vertices' payloads are
                         packed on-chip and gathers on them skip the HBM

@@ -58,7 +58,7 @@ def test_builder_patterns_hazard_free():
         b = ScheduleBuilder()
         b.gather_loop("g", n)
         b.pingpong_loop(["c", "w"], n, reads_per_chunk=2)
-        b.writeback_loop("wb", n)
+        b.flush_loop("wb", n)
         assert dma_hazards.check_schedule(b.ops, f"patterns[{n}]") == []
 
 
@@ -145,7 +145,9 @@ def test_dropping_any_wait_is_caught(drop):
 def test_pinning_pingpong_to_one_slot_is_caught(kind, seed):
     from repro.kernels.fused_superstep.fused_superstep import dma_schedule
     ops = dma_schedule(kind)
-    bufs = [b for b in schedule_buffers(ops) if b != "wbuf"]
+    # Only ping-pong buffers (those using both slots) can be pinned.
+    bufs = [b for b in schedule_buffers(ops)
+            if any(op.slot == 1 for op in ops if op.buffer == b)]
     buf = bufs[seed % len(bufs)]
     mutated = [op._replace(slot=0) if op.buffer == buf else op
                for op in ops]

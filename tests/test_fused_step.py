@@ -95,7 +95,10 @@ def _jaxrandom_task_uniforms(base_key, qid, hop, num, salt=0, epoch=None):
 @pytest.mark.parametrize("salt", [0, 2, 8, 17])
 def test_rng_bit_equal_to_jax_random(epoch_kind, salt, rng):
     """`rng.threefry2x32`-based task_uniforms == the jax.random fold chain,
-    across epochs, salts, and odd/even draw counts."""
+    across epochs, salts, and odd/even draw counts.  `core.rng` implements
+    the non-partitionable Threefry layout (two draws per cipher block), so
+    the reference runs under ``jax.threefry_partitionable(False)``; JAX's
+    default partitionable layout draws each word from its own block."""
     key = jax.random.PRNGKey(123)
     qid = jnp.asarray(rng.integers(0, 5000, 64), jnp.int32)
     hop = jnp.asarray(rng.integers(0, 80, 64), jnp.int32)
@@ -103,20 +106,23 @@ def test_rng_bit_equal_to_jax_random(epoch_kind, salt, rng):
              "mixed": jnp.asarray(rng.integers(0, 9, 64), jnp.int32)}[
         epoch_kind]
     for num in (1, 2, 5, 24):
-        ref = _jaxrandom_task_uniforms(key, qid, hop, num, salt, epoch)
+        with jax.threefry_partitionable(False):
+            ref = _jaxrandom_task_uniforms(key, qid, hop, num, salt, epoch)
         got = task_rng.task_uniforms(key, qid, hop, num, salt, epoch)
         assert np.array_equal(np.asarray(ref), np.asarray(got)), num
 
 
 def test_threefry_primitive_bit_equal():
-    """The shared block cipher itself matches jax.random.bits."""
+    """The shared block cipher itself matches jax.random.bits under the
+    non-partitionable Threefry layout (``fold_in`` is layout-free)."""
     key = jax.random.PRNGKey(7)
     folded = jax.random.fold_in(key, 42)
     y0, y1 = task_rng.threefry2x32(key[0], key[1], jnp.uint32(0),
                                    jnp.uint32(42))
     assert np.array_equal(np.asarray(folded), np.asarray([y0, y1]))
     for num in (1, 2, 3, 8, 9):
-        ref = jax.random.bits(folded, (num,), jnp.uint32)
+        with jax.threefry_partitionable(False):
+            ref = jax.random.bits(folded, (num,), jnp.uint32)
         got = task_rng.key_bits(folded[0], folded[1], num)
         assert np.array_equal(np.asarray(ref), np.asarray(got).reshape(-1))
 
@@ -216,19 +222,17 @@ def test_fused_reservoir_in_kernel_no_fallback(weighted_graph, rng):
 
 
 def test_fused_reservoir_adaptive_vs_fixed_chunks(weighted_graph, rng):
-    """Degree-adaptive trip bounding is a pure machine knob: adaptive and
-    fixed chunk counts sample identical paths, on both engines (chunks
-    past a lane's degree contribute only -inf reservoir keys)."""
+    """Both engines scan only each lane's own degree — the jnp scan packs
+    the live lanes' positions, the fused kernel runs ``ceil(deg/chunk)``
+    trips per lane — and sample identical paths for any chunk width
+    (chunks past a lane's degree contribute only -inf reservoir keys)."""
     starts = rng.integers(0, weighted_graph.num_vertices, 40).astype(np.int32)
-    runs = []
-    for adaptive in (True, False):
+    for chunk in (8, 3):
         spec = SamplerSpec(kind="reservoir_n2v", p=2.0, q=0.5,
-                           reservoir_chunk=8, adaptive_chunks=adaptive)
-        runs.append(_run_walks(weighted_graph, starts, spec, CFG, seed=3))
-        runs.append(_run_walks(weighted_graph, starts, spec, _fused(CFG),
-                               seed=3))
-    for r in runs[1:]:
-        _assert_same_run(runs[0], r)
+                           reservoir_chunk=chunk)
+        ref = _run_walks(weighted_graph, starts, spec, CFG, seed=3)
+        _assert_same_run(ref, _run_walks(weighted_graph, starts, spec,
+                                         _fused(CFG), seed=3))
 
 
 def test_fused_reservoir_partial_final_chunks(rng):
@@ -365,7 +369,7 @@ def _assert_same_walks_mod_cache(r_off, r_on):
 @pytest.mark.parametrize("algo", sorted(SPECS))
 @pytest.mark.parametrize("mode", ["zero_bubble", "static"])
 def test_cached_fused_closed_bit_identical(algo, mode, fused_graph, rng):
-    """Closed batch: the VMEM hot-vertex cache is invisible in every
+    """Closed batch: the SMEM hot-vertex cache is invisible in every
     sampled walk and every pre-existing stat — hits read the same bytes
     from a different tier — while the new counters show it actually
     served traffic (nonzero hits on the skewed fixture, all-zero when

@@ -6,6 +6,7 @@ import pytest
 
 from conftest import hypothesis_or_stubs
 from repro.graph import make_dataset
+from repro.kernels.common import graph_rows
 from repro.kernels.embedding_bag import embedding_bag
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 from repro.kernels.segment_sum import segment_sum
@@ -25,8 +26,7 @@ def graph():
 def test_walk_step_uniform_sweep(graph, W, tile, rng):
     v = jnp.asarray(rng.integers(0, graph.num_vertices, W), jnp.int32)
     u = jnp.asarray(rng.random(W), jnp.float32)
-    vn, dg = ws_ops.walk_step_uniform(v, u, graph.row_ptr, graph.col,
-                                      tile=tile)
+    vn, dg = ws_ops.walk_step_uniform(v, u, graph_rows(graph), tile=tile)
     vr, dr = ws_ref.walk_step_uniform_ref(v, u, graph.row_ptr, graph.col)
     np.testing.assert_array_equal(np.asarray(vn), np.asarray(vr))
     np.testing.assert_array_equal(np.asarray(dg), np.asarray(dr))
@@ -37,10 +37,9 @@ def test_walk_step_alias_sweep(graph, W, tile, rng):
     v = jnp.asarray(rng.integers(0, graph.num_vertices, W), jnp.int32)
     u1 = jnp.asarray(rng.random(W), jnp.float32)
     u2 = jnp.asarray(rng.random(W), jnp.float32)
-    args = (v, u1, u2, graph.row_ptr, graph.col, graph.alias_prob,
-            graph.alias_idx)
-    vn, dg = ws_ops.walk_step_alias(*args, tile=tile)
-    vr, dr = ws_ref.walk_step_alias_ref(*args)
+    vn, dg = ws_ops.walk_step_alias(v, u1, u2, graph_rows(graph), tile=tile)
+    vr, dr = ws_ref.walk_step_alias_ref(v, u1, u2, graph.row_ptr, graph.col,
+                                        graph.alias_prob, graph.alias_idx)
     np.testing.assert_array_equal(np.asarray(vn), np.asarray(vr))
     np.testing.assert_array_equal(np.asarray(dg), np.asarray(dr))
 
@@ -52,7 +51,7 @@ def test_walk_step_dangling_vertices(graph):
     assert dang.size > 0
     v = jnp.asarray(dang[:32], jnp.int32)
     u = jnp.zeros((v.shape[0],), jnp.float32)
-    vn, dg = ws_ops.walk_step_uniform(v, u, graph.row_ptr, graph.col, tile=32)
+    vn, dg = ws_ops.walk_step_uniform(v, u, graph_rows(graph), tile=32)
     assert (np.asarray(vn) == -1).all()
     assert (np.asarray(dg) == 0).all()
 
@@ -124,3 +123,44 @@ def test_embedding_bag_all_padding():
     tbl = jnp.ones((10, 8), jnp.float32)
     out = embedding_bag(idx, tbl, tile_b=4)
     assert (np.asarray(out) == 0).all()
+
+
+def _primitives(jaxpr, in_loop=False, seen=None):
+    """Primitive names of ``jaxpr``, split by whether they sit inside a
+    ``while`` body (the engine's superstep loop) or outside it."""
+    seen = seen if seen is not None else {"outside": set(), "loop": set()}
+    for eqn in jaxpr.eqns:
+        seen["loop" if in_loop else "outside"].add(eqn.primitive.name)
+        inner = in_loop or eqn.primitive.name == "while"
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else [p]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, inner, seen)
+    return seen
+
+
+@pytest.mark.parametrize("impl,runner", [
+    ("pallas", "closed"), ("fused", "closed"),
+    ("pallas", "stream"), ("fused", "stream")])
+def test_kernel_rows_laid_out_once_per_run(graph, impl, runner):
+    """The graph's row layout (`kernels.common.graph_rows`) is built
+    before the superstep loop, never re-padded per hop or per launch."""
+    import jax
+
+    from repro.core import EngineConfig
+    from repro.core.samplers import SamplerSpec
+    from repro.core.walk_engine import (build_engine, init_stream_state,
+                                        make_superstep_runner)
+    spec = SamplerSpec(kind="alias")
+    cfg = EngineConfig(num_slots=32, max_hops=4, step_impl=impl)
+    if runner == "closed":
+        run = build_engine(spec, cfg)
+        jaxpr = jax.make_jaxpr(lambda g, s: run(g, s, 0, num_queries=64))(
+            graph, jnp.zeros((64,), jnp.int32))
+    else:
+        run = make_superstep_runner(spec, cfg)
+        jaxpr = jax.make_jaxpr(run)(graph, init_stream_state(cfg, 64), 0, 8)
+    seen = _primitives(jaxpr.jaxpr)
+    assert "pad" in seen["outside"]
+    assert "pad" not in seen["loop"]

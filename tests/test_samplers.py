@@ -135,14 +135,46 @@ def test_stateless_rng_reproducible():
     assert not np.array_equal(np.asarray(u1[0]), np.asarray(u1[2]))
 
 
-def test_reservoir_adaptive_chunks_bit_identical(rng):
-    """Degree-adaptive E-S scan (dynamic chunk bound at the live lanes'
-    max degree) samples exactly the same walks as the full
-    ceil(max_degree/chunk) scan — the skipped chunks only ever held -inf
-    reservoir keys."""
-    import dataclasses
+def _chunked_reservoir_reference(spec, g, v_curr, v_prev, qid, hop, key):
+    """The E-S reservoir fold as the chunked-loop program defines it:
+    every lane scans all ``ceil(max_degree/chunk)`` chunks of its row
+    (the sharded lowering's gather/score primitives), keeping the
+    earliest maximal key.  Returns the chosen offset per lane."""
+    from repro.core import rng as task_rng
+    from repro.core.phase_program import chunk_gather
+    from repro.core.rng import SALT_CHUNK0
+    from repro.core.samplers import (es_chunk_score, es_merge,
+                                     es_num_chunks, n2v_bias)
+    CH = spec.reservoir_chunk
+    v_curr = jnp.asarray(v_curr, jnp.int32)
+    v_prev = jnp.asarray(v_prev, jnp.int32)
+    qid = jnp.asarray(qid, jnp.int32)
+    hop = jnp.asarray(hop, jnp.int32)
+    epoch = jnp.zeros_like(qid)
+    addr = g.row_ptr[v_curr]
+    deg = g.row_ptr[v_curr + 1] - addr
 
+    def body(c, carry):
+        u = task_rng.task_uniforms(key, qid, hop, CH, SALT_CHUNK0 + c,
+                                   epoch=epoch)
+        y, w_edge = chunk_gather(g, addr, deg, jnp.full_like(qid, c), CH)
+        w = w_edge * n2v_bias(spec, g, v_prev, y)
+        c_best, c_key = es_chunk_score(u, y >= 0, w)
+        return es_merge(*carry, c, CH, c_best, c_key)
+
+    init = (jnp.full(qid.shape, -jnp.inf), jnp.zeros(qid.shape, jnp.int32))
+    _, best = jax.lax.fori_loop(0, es_num_chunks(g.max_degree, CH), body,
+                                init)
+    return np.asarray(jnp.clip(best, 0, jnp.maximum(deg - 1, 0)))
+
+
+def test_reservoir_adaptive_chunks_bit_identical(rng):
+    """The engine's reservoir scan scores only the live lanes' positions;
+    every hop it samples must be the hop the full chunked fold (all
+    ``ceil(max_degree/chunk)`` chunks per lane) picks — chunks past a
+    lane's degree only ever hold -inf reservoir keys."""
     from repro.core import EngineConfig
+    from repro.core import rng as task_rng
     from repro.core.walk_engine import _run_walks
     from repro.graph import make_dataset
 
@@ -150,12 +182,62 @@ def test_reservoir_adaptive_chunks_bit_identical(rng):
     starts = rng.integers(0, g.num_vertices, 150).astype(np.int32)
     spec = SamplerSpec(kind="reservoir_n2v", p=2.0, q=0.5,
                        reservoir_chunk=16)
-    assert spec.adaptive_chunks  # the default
     cfg = EngineConfig(num_slots=32, max_hops=8)
-    fixed = dataclasses.replace(spec, adaptive_chunks=False)
-    r_ad = _run_walks(g, starts, spec, cfg, seed=3)
-    r_fx = _run_walks(g, starts, fixed, cfg, seed=3)
-    pa, la = r_ad.as_numpy()
-    pf, lf = r_fx.as_numpy()
-    assert np.array_equal(pa, pf)
-    assert np.array_equal(la, lf)
+    paths, lengths = _run_walks(g, starts, spec, cfg, seed=3).as_numpy()
+    q, h = np.nonzero(np.arange(cfg.max_hops)[None, :] < lengths[:, None] - 1)
+    v_prev = np.where(h > 0, paths[q, np.maximum(h - 1, 0)], -1)
+    idx = _chunked_reservoir_reference(spec, g, paths[q, h], v_prev, q, h,
+                                       task_rng.stream_key(3))
+    rp = np.asarray(g.row_ptr)
+    want = np.asarray(g.col)[rp[paths[q, h]] + idx]
+    assert q.size > 500
+    np.testing.assert_array_equal(paths[q, h + 1], want)
+
+
+@pytest.mark.parametrize("num_lanes,chunk,active_frac", [
+    (8, 4, 1.0),      # 32-item blocks: hub lanes span many blocks
+    (16, 16, 0.5),    # half the lanes idle, holes in the packing
+    (5, 3, 0.8),      # odd chunk: uniforms from both Threefry words
+    (64, 64, 1.0),    # one block holds most lanes whole
+])
+def test_reservoir_scan_matches_chunked_fold(num_lanes, chunk, active_frac):
+    """`reservoir_scan` on arbitrary lane states (hubs, degree-0 and
+    idle lanes, lanes split across blocks) picks the chunked fold's
+    offset on every live lane with a neighbor."""
+    from repro.core import rng as task_rng
+    from repro.core.phase_program import reservoir_scan
+
+    r = np.random.default_rng(num_lanes * 100 + chunk)
+    n = 40
+    edges = [(0, v) for v in range(1, n)] + [(v, 0) for v in range(1, n)]
+    edges += [(v, v % (n - 1) + 1) for v in range(1, n)]
+    edges += [(v % (n - 1) + 1, v) for v in range(1, n)]
+    edges += [(int(a), int(b)) for a, b in r.integers(1, n, (60, 2))
+              if a != b]
+    edges = np.unique(np.asarray(edges, np.int64), axis=0)
+    g = build_csr(edges, n + 1,   # vertex n has no out-edges
+                  weights=r.random(len(edges)).astype(np.float32) + 1e-3)
+    spec = SamplerSpec(kind="reservoir_n2v", p=2.0, q=0.5,
+                       reservoir_chunk=chunk)
+    v_curr = r.integers(0, n + 1, num_lanes).astype(np.int32)
+    v_curr[0] = 0                                    # a hub lane
+    v_prev = r.integers(-1, n, num_lanes).astype(np.int32)
+    active = r.random(num_lanes) < active_frac
+    active[0] = True
+    key = task_rng.stream_key(11)
+    slots = WalkerSlots(
+        v_curr=jnp.asarray(v_curr), v_prev=jnp.asarray(v_prev),
+        query_id=jnp.arange(num_lanes, dtype=jnp.int32) * 7,
+        hop=jnp.asarray(r.integers(0, 9, num_lanes).astype(np.int32)),
+        active=jnp.asarray(active),
+        epoch=jnp.zeros((num_lanes,), jnp.int32))
+    addr = g.row_ptr[slots.v_curr]
+    deg = g.row_ptr[slots.v_curr + 1] - addr
+    idx, ok = reservoir_scan(spec, g, addr, deg, slots, key)
+    want = _chunked_reservoir_reference(spec, g, v_curr, v_prev,
+                                        slots.query_id, slots.hop, key)
+    use = active & np.asarray(ok)
+    assert use.sum() >= 2
+    np.testing.assert_array_equal(np.asarray(idx)[use], want[use])
+
+

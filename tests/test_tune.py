@@ -107,7 +107,7 @@ def test_enumeration_excludes_resampling_knobs_by_default():
     cands = tune.enumerate_candidates(prog, ex)
     chunks = {c.get("reservoir_chunk") for c in cands}
     assert chunks == {prog.spec.reservoir_chunk}  # pinned, never enumerated
-    assert {c.get("adaptive_chunks") for c in cands} == {True, False}
+    assert all("adaptive_chunks" not in c.to_dict() for c in cands)
     with_rs = tune.enumerate_candidates(prog, ex, include_resampling=True)
     assert len({c.get("reservoir_chunk") for c in with_rs}) > 1
 
@@ -125,23 +125,22 @@ def test_hops_per_launch_only_on_fused():
 # ------------------------------------------------------------------ model
 
 
-def test_adaptive_gate_on_skewed_off_balanced():
-    # Power-law tail: most edge mass sits at modest degrees, the max is an
-    # outlier -> live lanes stay far below max_degree -> gate opens.
+def test_reservoir_trips_follow_lane_degree():
+    """The model prices a reservoir hop at the chunk trips of a typical
+    walking lane (degree-weighted median degree), not the graph's
+    max-degree bound: a power-law tail leaves the price unchanged."""
     assert len(WEIGHTED_QS) == 8
-    skewed = GraphSignature(
-        num_vertices=4096, num_edges=32768, max_degree=600,
+    mk = lambda dmax: GraphSignature(  # noqa: E731
+        num_vertices=4096, num_edges=32768, max_degree=dmax,
         weighted=True, typed=False,
-        deg_q=(1, 2, 4, 8, 16, 300, 600),
-        deg_wq=(20, 40, 80, 120, 160, 400, 600, 600))
-    assert tune.adaptive_chunk_gate(skewed, num_slots=32, chunk=16)
-    # Balanced: live max ~= max degree -> adaptive cannot win -> gate off.
-    balanced = GraphSignature(
-        num_vertices=4096, num_edges=32768, max_degree=20,
-        weighted=True, typed=False,
-        deg_q=(12, 14, 15, 16, 17, 19, 20),
-        deg_wq=(16, 17, 18, 18, 19, 19, 20, 20))
-    assert not tune.adaptive_chunk_gate(balanced, num_slots=32, chunk=64)
+        deg_q=(1, 2, 4, 8, 16, 300, dmax),
+        deg_wq=(20, 40, 80, 120, 160, 400, 600, dmax))
+    spec = WalkProgram.node2vec(2.0, 0.5, 8, weighted=True).spec
+    d = tune.lane_degree(mk(600))
+    assert d == 20      # the weighted ladder's median rung
+    assert tune.lane_degree(mk(60000)) == d
+    assert (tune.bytes_per_hop(spec, mk(600))
+            < tune.bytes_per_hop(spec, mk(600), chunk_trips=600))
 
 
 def test_bytes_per_hop_orders_sampler_kinds(weighted_graph):
@@ -278,10 +277,12 @@ def test_execution_config_auto_validation():
 
 
 def test_sampler_spec_adaptive_auto_validation():
+    """Reservoir specs carry no scan-shape option; a malformed chunk
+    width still fails at construction."""
     spec = WalkProgram.node2vec(2.0, 0.5, 8, weighted=True).spec
-    assert spec.adaptive_chunks == "auto"
+    assert not hasattr(spec, "adaptive_chunks")
     with pytest.raises(ValueError):
-        dataclasses.replace(spec, adaptive_chunks="sometimes")
+        dataclasses.replace(spec, reservoir_chunk=0)
 
 
 def test_auto_resolution_preserves_paths(small_graph):
@@ -313,15 +314,17 @@ def test_auto_resolution_uses_cached_entry(small_graph, tmp_path):
 
 
 def test_reservoir_auto_gate_resolution(weighted_graph):
+    """A reservoir program with concrete execution knobs has nothing to
+    resolve: `resolve` hands it back unchanged."""
     prog = WalkProgram.node2vec(2.0, 0.5, 8, weighted=True)
     ex = ExecutionConfig(num_slots=32, record_paths=False)
-    assert tune.needs_resolution(prog, ex)    # adaptive_chunks == "auto"
-    prog2, _ = tune.resolve(prog, ex, weighted_graph,
-                            cache=tune.TuningCache(None))
-    assert prog2.spec.adaptive_chunks in (True, False)
-    sig = tune.graph_signature(weighted_graph)
-    assert prog2.spec.adaptive_chunks == tune.adaptive_chunk_gate(
-        sig, 32, prog.spec.reservoir_chunk)
+    assert not tune.needs_resolution(prog, ex)
+    prog2, ex2 = tune.resolve(prog, ex, weighted_graph,
+                              cache=tune.TuningCache(None))
+    assert prog2 is prog and ex2 is ex
+    prog3, _ = tune.resolve(prog, ExecutionConfig(num_slots="auto"),
+                            weighted_graph, cache=tune.TuningCache(None))
+    assert prog3 is prog
 
 
 SHARDED_AUTO = r"""

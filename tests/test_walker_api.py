@@ -147,6 +147,21 @@ def test_sharded_metapath_needs_typed_partition(small_graph, rng):
         w.run(pg, starts)
 
 
+def test_sharded_run_refuses_more_partitions_than_devices(small_graph,
+                                                          rng):
+    """A mesh is never built over fewer devices than graph partitions."""
+    import jax
+
+    from repro.graph import partition_graph
+    n = len(jax.devices()) + 1
+    pg = partition_graph(small_graph, n)
+    starts = rng.integers(0, small_graph.num_vertices, 16).astype(np.int32)
+    w = walker.compile(_programs()["urw"], backend="sharded",
+                       execution=walker.ExecutionConfig(num_devices=n))
+    with pytest.raises(ValueError, match="partitions but only"):
+        w.run(pg, starts)
+
+
 # ------------------------------------------------------------ validation
 
 
