@@ -1,0 +1,12 @@
+import os
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+# The tests compile on the CPU; keep their programs out of the checkout's
+# persistent compilation cache.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
