@@ -1,6 +1,11 @@
 """Single-device walk engine: out-of-order slot-pool execution with
 zero-bubble refill (paper §V + §VI, adapted to a SIMD superstep machine).
 
+The per-hop superstep names its phases with ``jax.named_scope``
+(``walk.sample``, ``walk.commit``, ``walk.refill``, ``walk.record``); the
+names ride on the compiled ops' metadata, so a device trace can split the
+superstep's time by phase at no run-time cost.
+
 One *superstep* advances every live lane by one hop through the paper's
 three stages — Row Access → Sampling → Column Access — then terminates
 finished walks and immediately refills freed lanes from the pending-query
@@ -290,34 +295,37 @@ def _refill(slots: WalkerSlots, queue: QueryQueue, paths, lengths,
     from the order ring — the slot id, start vertex, and epoch of occupant
     ``head + rank`` all come from the ring, so reclaimed slots are re-issued
     transparently."""
-    free = (~slots.active) | terminated
-    if cfg.mode == "static":
-        # Bulk-synchronous: only reload when the whole batch drained.
-        all_free = jnp.all(free)
-        free = free & all_free
-    avail = jnp.maximum(queue.staged - queue.head, 0)
-    rank = jnp.cumsum(free.astype(jnp.int32)) - 1           # rank among free lanes
-    take = free & (rank < avail)
-    nq = queue.capacity
-    pos = (queue.head + jnp.maximum(rank, 0)) % nq          # arrival seq -> ring
-    qid = queue.order[pos]
-    start = queue.start_vertex[qid]
-    ep = queue.epoch[qid]
+    with jax.named_scope("walk.refill"):
+        free = (~slots.active) | terminated
+        if cfg.mode == "static":
+            # Bulk-synchronous: only reload when the whole batch drained.
+            all_free = jnp.all(free)
+            free = free & all_free
+        avail = jnp.maximum(queue.staged - queue.head, 0)
+        rank = jnp.cumsum(free.astype(jnp.int32)) - 1   # rank among free lanes
+        take = free & (rank < avail)
+        nq = queue.capacity
+        pos = (queue.head + jnp.maximum(rank, 0)) % nq  # arrival seq -> ring
+        qid = queue.order[pos]
+        start = queue.start_vertex[qid]
+        ep = queue.epoch[qid]
 
-    new_slots = WalkerSlots(
-        v_curr=jnp.where(take, start, slots.v_curr),
-        v_prev=jnp.where(take, -1, slots.v_prev),
-        query_id=jnp.where(take, qid, jnp.where(terminated, -1, slots.query_id)),
-        hop=jnp.where(take, 0, slots.hop),
-        active=jnp.where(take, True, slots.active & ~terminated),
-        epoch=jnp.where(take, ep, slots.epoch),
-    )
-    n_taken = jnp.sum(take.astype(jnp.int32))
-    new_queue = queue._replace(head=queue.head + n_taken)
+        new_slots = WalkerSlots(
+            v_curr=jnp.where(take, start, slots.v_curr),
+            v_prev=jnp.where(take, -1, slots.v_prev),
+            query_id=jnp.where(take, qid,
+                               jnp.where(terminated, -1, slots.query_id)),
+            hop=jnp.where(take, 0, slots.hop),
+            active=jnp.where(take, True, slots.active & ~terminated),
+            epoch=jnp.where(take, ep, slots.epoch),
+        )
+        n_taken = jnp.sum(take.astype(jnp.int32))
+        new_queue = queue._replace(head=queue.head + n_taken)
     if cfg.record_paths:
-        scatter_q = jnp.where(take, qid, nq)  # nq = OOB -> dropped
-        paths = paths.at[scatter_q, 0].set(start, mode="drop")
-        lengths = lengths.at[scatter_q].set(1, mode="drop")
+        with jax.named_scope("walk.record"):
+            scatter_q = jnp.where(take, qid, nq)  # nq = OOB -> dropped
+            paths = paths.at[scatter_q, 0].set(start, mode="drop")
+            lengths = lengths.at[scatter_q].set(1, mode="drop")
     return new_slots, new_queue, paths, lengths
 
 
@@ -331,11 +339,12 @@ def _advance_controller(queue: QueryQueue, head_hist: jnp.ndarray,
     ``head_hist`` holds the last C+1 head observations; pushing the current
     head first and reading index 0 yields the head from exactly C
     supersteps ago (the freshest observation available under the delay)."""
-    head_hist = jnp.concatenate([head_hist[1:], queue.head[None]])
-    delayed_head = head_hist[0]
-    target = jnp.minimum(delayed_head + depth, queue.tail)
-    staged = jnp.maximum(queue.staged, target)
-    return queue._replace(staged=staged), head_hist
+    with jax.named_scope("walk.refill"):
+        head_hist = jnp.concatenate([head_hist[1:], queue.head[None]])
+        delayed_head = head_hist[0]
+        target = jnp.minimum(delayed_head + depth, queue.tail)
+        staged = jnp.maximum(queue.staged, target)
+        return queue._replace(staged=staged), head_hist
 
 
 def _process(graph: CSRGraph, rows, spec: SamplerSpec, cfg: EngineConfig,
@@ -346,56 +355,64 @@ def _process(graph: CSRGraph, rows, spec: SamplerSpec, cfg: EngineConfig,
     walk-step kernel."""
     A = slots.active
 
-    # PPR teleport/termination draw (before the hop; geometric walk length).
-    if spec.stop_prob > 0.0:
-        u_stop = task_rng.task_uniforms(base_key, slots.query_id, slots.hop,
-                                        1, SALT_STOP, epoch=slots.epoch)[:, 0]
-        stop = A & (u_stop < spec.stop_prob)
-    else:
-        stop = jnp.zeros_like(A)
-
-    if cfg.step_impl == "pallas" and lower_program(spec).pallas:
-        # Fused Pallas walk-step kernel (async DMA pipeline, kernels/walk_step).
-        from repro.kernels.walk_step import ops as walk_ops
-        if spec.kind == "uniform":
-            u = task_rng.task_uniforms(base_key, slots.query_id, slots.hop,
-                                       1, SALT_COLUMN, epoch=slots.epoch)
-            v_next, deg = walk_ops.walk_step_uniform(
-                slots.v_curr, u[:, 0], rows)
+    with jax.named_scope("walk.sample"):
+        # PPR teleport/termination draw (before the hop; geometric length).
+        if spec.stop_prob > 0.0:
+            u_stop = task_rng.task_uniforms(
+                base_key, slots.query_id, slots.hop, 1, SALT_STOP,
+                epoch=slots.epoch)[:, 0]
+            stop = A & (u_stop < spec.stop_prob)
         else:
-            u = task_rng.task_uniforms(base_key, slots.query_id, slots.hop,
-                                       2, SALT_COLUMN, epoch=slots.epoch)
-            v_next, deg = walk_ops.walk_step_alias(
-                slots.v_curr, u[:, 0], u[:, 1], rows)
-        ok = deg > 0
-    else:
-        addr, deg = row_access(graph, slots.v_curr)           # stage 1
-        sampler = make_sampler(spec)                          # phase program
-        idx, ok = sampler(graph, addr, deg, slots, base_key)  # stage 2
-        v_next = column_access(graph, addr, idx)              # stage 3
+            stop = jnp.zeros_like(A)
 
-    adv = A & ~stop & ok
-    dead = A & ~stop & ~ok
-    new_hop = jnp.where(adv, slots.hop + 1, slots.hop)
-    reached_max = adv & (new_hop >= cfg.max_hops)
-    terminated = stop | dead | reached_max
+        if cfg.step_impl == "pallas" and lower_program(spec).pallas:
+            # Fused Pallas walk-step kernel (async DMA pipeline,
+            # kernels/walk_step).
+            from repro.kernels.walk_step import ops as walk_ops
+            if spec.kind == "uniform":
+                u = task_rng.task_uniforms(base_key, slots.query_id,
+                                           slots.hop, 1, SALT_COLUMN,
+                                           epoch=slots.epoch)
+                v_next, deg = walk_ops.walk_step_uniform(
+                    slots.v_curr, u[:, 0], rows)
+            else:
+                u = task_rng.task_uniforms(base_key, slots.query_id,
+                                           slots.hop, 2, SALT_COLUMN,
+                                           epoch=slots.epoch)
+                v_next, deg = walk_ops.walk_step_alias(
+                    slots.v_curr, u[:, 0], u[:, 1], rows)
+            ok = deg > 0
+        else:
+            addr, deg = row_access(graph, slots.v_curr)           # stage 1
+            sampler = make_sampler(spec)                          # phase program
+            idx, ok = sampler(graph, addr, deg, slots, base_key)  # stage 2
+            v_next = column_access(graph, addr, idx)              # stage 3
 
-    new_slots = WalkerSlots(
-        v_curr=jnp.where(adv, v_next, slots.v_curr),
-        v_prev=jnp.where(adv, slots.v_curr, slots.v_prev),
-        query_id=slots.query_id,
-        hop=new_hop,
-        active=slots.active,
-        epoch=slots.epoch,
-    )
+    with jax.named_scope("walk.commit"):
+        adv = A & ~stop & ok
+        dead = A & ~stop & ~ok
+        new_hop = jnp.where(adv, slots.hop + 1, slots.hop)
+        reached_max = adv & (new_hop >= cfg.max_hops)
+        terminated = stop | dead | reached_max
+
+        new_slots = WalkerSlots(
+            v_curr=jnp.where(adv, v_next, slots.v_curr),
+            v_prev=jnp.where(adv, slots.v_curr, slots.v_prev),
+            query_id=slots.query_id,
+            hop=new_hop,
+            active=slots.active,
+            epoch=slots.epoch,
+        )
     if cfg.record_paths:
-        nq = paths.shape[0]
-        scatter_q = jnp.where(adv, slots.query_id, nq)
-        paths = paths.at[scatter_q, new_hop].set(v_next, mode="drop")
-        lengths = lengths.at[scatter_q].set(new_hop + 1, mode="drop")
-    nd = done.shape[0]
-    scatter_d = jnp.where(terminated & A, slots.query_id, nd)
-    done = done.at[scatter_d].set(True, mode="drop")
+        with jax.named_scope("walk.record"):
+            nq = paths.shape[0]
+            scatter_q = jnp.where(adv, slots.query_id, nq)
+            paths = paths.at[scatter_q, new_hop].set(v_next, mode="drop")
+            lengths = lengths.at[scatter_q].set(new_hop + 1, mode="drop")
+    with jax.named_scope("walk.commit"):
+        nd = done.shape[0]
+        scatter_d = jnp.where(terminated & A, slots.query_id, nd)
+        done = done.at[scatter_d].set(True, mode="drop")
     return new_slots, terminated, adv, paths, lengths, done
 
 
@@ -407,24 +424,25 @@ def _superstep(graph, rows, spec, cfg, base_key, depth,
     slots, terminated, adv, paths, lengths, done = _process(
         graph, rows, spec, cfg, base_key, slots, paths, lengths, done)
 
-    n_active = jnp.sum(slots.active.astype(jnp.int32))
-    idle = W - n_active
-    # Idle lanes while unserved queries exist upstream = scheduler
-    # starvation (what Theorem VI.1 eliminates); idle lanes after the last
-    # arrived query was issued = unavoidable tail drain.
-    upstream = (queue.head < queue.tail).astype(jnp.int32)
-    stats = stats._replace(
-        steps=stats.steps + jnp.sum(adv.astype(jnp.int32)),
-        slot_steps=stats.slot_steps + W,
-        bubbles=stats.bubbles + idle,
-        starved=stats.starved + idle * upstream,
-        terminations=stats.terminations
-        + jnp.sum((terminated & slots.active).astype(jnp.int32)),
-        supersteps=stats.supersteps + 1,
-        # The per-hop impls dispatch one device program per superstep; the
-        # fused kernel instead counts one launch per k supersteps.
-        launches=stats.launches + 1,
-    )
+    with jax.named_scope("walk.commit"):
+        n_active = jnp.sum(slots.active.astype(jnp.int32))
+        idle = W - n_active
+        # Idle lanes while unserved queries exist upstream = scheduler
+        # starvation (what Theorem VI.1 eliminates); idle lanes after the
+        # last arrived query was issued = unavoidable tail drain.
+        upstream = (queue.head < queue.tail).astype(jnp.int32)
+        stats = stats._replace(
+            steps=stats.steps + jnp.sum(adv.astype(jnp.int32)),
+            slot_steps=stats.slot_steps + W,
+            bubbles=stats.bubbles + idle,
+            starved=stats.starved + idle * upstream,
+            terminations=stats.terminations
+            + jnp.sum((terminated & slots.active).astype(jnp.int32)),
+            supersteps=stats.supersteps + 1,
+            # The per-hop impls dispatch one device program per superstep;
+            # the fused kernel instead counts one launch per k supersteps.
+            launches=stats.launches + 1,
+        )
 
     queue, head_hist = _advance_controller(queue, head_hist, cfg, depth)
     slots, queue, paths, lengths = _refill(slots, queue, paths, lengths, cfg,

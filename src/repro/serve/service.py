@@ -22,6 +22,13 @@ The chunk size is the host-injection granularity: smaller chunks admit
 arrivals sooner (lower sojourn) at the cost of more host↔device syncs —
 the open-system analogue of the paper's §VI-A injection delay C.
 
+Each phase is a ``jax.profiler`` span (``serve.step`` around ``serve.admit``,
+``serve.advance``, ``serve.done_mask`` and one ``serve.harvest`` per
+finished request with its ``serve.release``), and :attr:`WalkService.counters`
+counts the chunks run and the stream's blocking device→host reads by site,
+so a trace or a window of counters says where a step's host time goes
+(``docs/api.md``, "Tracing a running service").
+
 Ring-buffer reclamation means the device buffer holds ``capacity`` *live*
 queries and completed slots go around again immediately — there is no
 drain barrier anywhere, so lanes stay busy across request boundaries
@@ -36,10 +43,11 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.samplers import SamplerSpec
 from repro.core.scheduler import ServiceAnalysis, analyze_service
@@ -158,7 +166,7 @@ class WalkService:
         self._inflight: Dict[int, WalkRequest] = {}
         self._completed: Dict[int, WalkRequest] = {}
         self._next_rid = 0
-        self._resets = 0
+        self._chunks = 0          # steps that ran a chunk
 
     # ------------------------------------------------------------ geometry
 
@@ -225,10 +233,23 @@ class WalkService:
         which may shrink/grow ``self.chunk`` for the *next* launch (an
         explicit ``k`` bypasses adaptation for this launch).
         """
-        self._admit()
+        with TraceAnnotation("serve.step") as span:
+            chunks, syncs = self._chunks, self.stream.num_syncs
+            ran = self._step(k)
+            # The step's share of the counters rides on its span, so a
+            # trace alone can read syncs per step.
+            span.set_metadata(chunks=self._chunks - chunks,
+                              syncs=self.stream.num_syncs - syncs)
+        return ran
+
+    def _step(self, k: Optional[int]) -> int:
+        with TraceAnnotation("serve.admit") as span:
+            span.set_metadata(admitted=self._admit())
         if not self._inflight:
             return 0
-        ran = self.stream.advance(self.chunk if k is None else int(k))
+        with TraceAnnotation("serve.advance"):
+            ran = self.stream.advance(self.chunk if k is None else int(k))
+        self._chunks += 1
         self.clock += ran
         self._harvest()
         if self._controller is not None and k is None and ran > 0:
@@ -259,16 +280,20 @@ class WalkService:
         return tuple(self._adaptation)
 
     def _harvest(self) -> None:
-        done = self.stream.done_mask()
+        with TraceAnnotation("serve.done_mask"):
+            done = self.stream.done_mask()
         finished: List[WalkRequest] = []
         for req in self._inflight.values():
             if done[req.qids].all():
                 finished.append(req)
         for req in finished:
-            req.paths, req.lengths = self.stream.harvest_ids(req.qids)
-            req.completed_at = self.clock
-            req.wall_completed = time.perf_counter()
-            self.stream.release(req.qids)   # slots go around again (epoch+1)
+            with TraceAnnotation("serve.harvest", request_id=req.request_id):
+                req.paths, req.lengths = self.stream.harvest_ids(req.qids)
+                req.completed_at = self.clock
+                req.wall_completed = time.perf_counter()
+                with TraceAnnotation("serve.release"):
+                    # slots go around again (epoch+1)
+                    self.stream.release(req.qids)
             del self._inflight[req.request_id]
             self._completed[req.request_id] = req
 
@@ -291,7 +316,6 @@ class WalkService:
         The stream is re-seeded so successive sweeps draw fresh walks."""
         if self._pending or self._inflight:
             raise RuntimeError("reset_metrics with requests outstanding")
-        self._resets += 1
         self.stream.reset(seed=self.stream.seed + 1)
         self.clock = 0
         self._completed.clear()
@@ -329,6 +353,18 @@ class WalkService:
     def walk_stats(self) -> WalkStats:
         """Engine counters since construction / reset (host ints)."""
         return self.stream.walk_stats()
+
+    @property
+    def counters(self) -> Counter:
+        """Host-side counts since construction (a copy; ``reset_metrics``
+        keeps them), to be differenced over a window as :meth:`walk_stats`
+        is: ``chunks``, the steps that ran a chunk, and ``sync.<site>``,
+        the stream's blocking device→host reads by site (``advance``,
+        ``done_mask``, ``harvest``, ``release``, ``walk_stats``)."""
+        out = Counter({f"sync.{site}": n
+                       for site, n in self.stream.syncs.items()})
+        out["chunks"] = self._chunks
+        return out
 
     def sojourns(self) -> List[int]:
         return [r.sojourn for r in self._completed.values()]

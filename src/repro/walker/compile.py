@@ -24,12 +24,13 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from collections import deque
+from collections import Counter, deque
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import corpus_ring
 from repro.core import rng as task_rng
@@ -181,55 +182,72 @@ class Walker:
         ``PartitionedGraph``; the emission logs are assembled into the same
         ``WalkResult`` layout as the single-device engine, with per-device
         stats summed.
+
+        Each call records ``walker.bind`` (program checks, knob
+        resolution), ``walker.engine`` (start upload, engine dispatch) and,
+        on the sharded backend, ``walker.assemble`` (path assembly from the
+        emission logs) as ``jax.profiler`` spans; they cost nothing
+        measurable when no trace is running.
         """
         if self.backend == "single":
-            self.program.requires(graph)
-            sv = jnp.asarray(starts, jnp.int32)
-            program, execution = self._bind(graph, int(sv.shape[0]))
-            return self._single_engine(program, execution, graph)(
-                graph, sv, seed, num_queries=int(sv.shape[0]))
+            with TraceAnnotation("walker.bind"):
+                self.program.requires(graph)
+                program, execution = self._bind(graph,
+                                                int(np.shape(starts)[0]))
+            with TraceAnnotation("walker.engine"):
+                sv = jnp.asarray(starts, jnp.int32)
+                return self._single_engine(program, execution, graph)(
+                    graph, sv, seed, num_queries=int(sv.shape[0]))
 
-        if not isinstance(graph, PartitionedGraph):
-            self.program.requires(graph)
-        elif self.program.spec.kind == "alias" and graph.alias_prob is None:
-            raise ValueError(
-                "alias (DeepWalk) programs need alias tables on the "
-                "partitioned graph — build the CSRGraph with alias tables "
-                "before partition_graph")
-        pg = self._partition(graph)
-        program, execution = self._bind(pg, np.asarray(starts).size)
-        run, cfg = self._dist_engine(pg, program, execution)
-        starts_np = np.asarray(starts, dtype=np.int32)
-        starts_sh, qcount = shard_starts(starts_np, pg.num_devices)
-        base_key = task_rng.stream_key(seed)
-        log_q, log_h, log_v, cursor, stats = run(
-            pg, jnp.asarray(starts_sh), jnp.asarray(qcount), base_key)
-        self.last_device_stats = WalkStats(*stats)
-        # Devices run the lockstep superstep loop the same number of times:
-        # supersteps/launches are global clocks (max), the rest is additive.
-        total = WalkStats(*(
-            jnp.max(v) if name in ("supersteps", "launches") else jnp.sum(v)
-            for name, v in zip(WalkStats._fields, stats)))
-        if int(total.supersteps) >= cfg.max_supersteps:
-            warnings.warn(
-                f"sharded run hit max_supersteps={cfg.max_supersteps} before "
-                "draining — walks may be truncated; raise "
-                "ExecutionConfig.max_supersteps", RuntimeWarning,
-                stacklevel=2)
-        if int(total.drops) > 0:
-            # Routing drops are structurally impossible (flow-controlled
-            # refill), so any drop is an emission-log overflow: recorded
-            # paths have holes.
-            warnings.warn(
-                f"{int(total.drops)} path records dropped (emission log "
-                "overflow) — assembled paths are incomplete; raise "
-                "ExecutionConfig.log_capacity", RuntimeWarning, stacklevel=2)
+        with TraceAnnotation("walker.bind"):
+            if not isinstance(graph, PartitionedGraph):
+                self.program.requires(graph)
+            elif (self.program.spec.kind == "alias"
+                  and graph.alias_prob is None):
+                raise ValueError(
+                    "alias (DeepWalk) programs need alias tables on the "
+                    "partitioned graph — build the CSRGraph with alias "
+                    "tables before partition_graph")
+            pg = self._partition(graph)
+            program, execution = self._bind(pg, np.asarray(starts).size)
+        with TraceAnnotation("walker.engine"):
+            run, cfg = self._dist_engine(pg, program, execution)
+            starts_np = np.asarray(starts, dtype=np.int32)
+            starts_sh, qcount = shard_starts(starts_np, pg.num_devices)
+            base_key = task_rng.stream_key(seed)
+            log_q, log_h, log_v, cursor, stats = run(
+                pg, jnp.asarray(starts_sh), jnp.asarray(qcount), base_key)
+            self.last_device_stats = WalkStats(*stats)
+            # Devices run the lockstep superstep loop the same number of
+            # times: supersteps/launches are global clocks (max), the rest
+            # is additive.
+            total = WalkStats(*(
+                jnp.max(v) if name in ("supersteps", "launches")
+                else jnp.sum(v)
+                for name, v in zip(WalkStats._fields, stats)))
+            if int(total.supersteps) >= cfg.max_supersteps:
+                warnings.warn(
+                    f"sharded run hit max_supersteps={cfg.max_supersteps} "
+                    "before draining — walks may be truncated; raise "
+                    "ExecutionConfig.max_supersteps", RuntimeWarning,
+                    stacklevel=2)
+            if int(total.drops) > 0:
+                # Routing drops are structurally impossible (flow-controlled
+                # refill), so any drop is an emission-log overflow: recorded
+                # paths have holes.
+                warnings.warn(
+                    f"{int(total.drops)} path records dropped (emission log "
+                    "overflow) — assembled paths are incomplete; raise "
+                    "ExecutionConfig.log_capacity", RuntimeWarning,
+                    stacklevel=2)
         if cfg.record_paths:
-            logs = DistLogs(qid=log_q, hop=log_h, vertex=log_v, cursor=cursor)
-            paths, lengths = assemble_paths(logs, starts_np,
-                                            self.program.max_hops)
-            return WalkResult(paths=jnp.asarray(paths),
-                              lengths=jnp.asarray(lengths), stats=total)
+            with TraceAnnotation("walker.assemble"):
+                logs = DistLogs(qid=log_q, hop=log_h, vertex=log_v,
+                                cursor=cursor)
+                paths, lengths = assemble_paths(logs, starts_np,
+                                                self.program.max_hops)
+                return WalkResult(paths=jnp.asarray(paths),
+                                  lengths=jnp.asarray(lengths), stats=total)
         dummy = jnp.full((1, 1), -1, jnp.int32)
         return WalkResult(paths=dummy, lengths=jnp.zeros((1,), jnp.int32),
                           stats=total)
@@ -469,6 +487,11 @@ class _StreamBase:
     derivation with the epoch).  The stream therefore never drains as a
     whole — slots individually complete, are harvested, and go around
     again.
+
+    Every blocking device→host read the stream makes is counted by site
+    (:attr:`syncs`), and :meth:`advance` records ``stream.sync`` /
+    ``stream.dispatch`` profiler spans, so a trace can split the blocking
+    reads from the engine dispatch.
     """
 
     capacity: int
@@ -485,18 +508,63 @@ class _StreamBase:
                        epochs: np.ndarray) -> None:
         raise NotImplementedError
 
-    def advance(self, k: int = 16) -> int:
-        """Run at most ``k`` supersteps on the persistent device state."""
+    def _dispatch(self, k: int) -> None:
+        """Enqueue at most ``k`` supersteps on the persistent device state."""
         raise NotImplementedError
 
-    def done_mask(self) -> np.ndarray:
-        """Per-slot completion flags (capacity-sized, includes free slots)."""
+    def _supersteps(self) -> int:
+        """The engine's superstep clock (one blocking read)."""
+        raise NotImplementedError
+
+    def _done_flags(self) -> np.ndarray:
+        """Per-slot completion flags (one blocking read)."""
         raise NotImplementedError
 
     def harvest_device(self, qids):
         """Fetch ``(paths, lengths)`` for the given live query-id slots as
         *device-resident* arrays (no host copy) — the corpus-ring feed."""
         raise NotImplementedError
+
+    # -- device reads --------------------------------------------------------
+
+    @property
+    def syncs(self) -> Counter:
+        """Blocking device→host reads since construction, by site (a copy):
+        ``advance`` (the superstep clock before and after a chunk),
+        ``done_mask``, ``harvest`` (paths and lengths of
+        :meth:`harvest_ids`), ``release`` (its ``done`` check) and
+        ``walk_stats`` (one per counter)."""
+        return Counter(self._syncs)
+
+    @property
+    def num_syncs(self) -> int:
+        """All blocking device→host reads since construction."""
+        return self._num_syncs
+
+    def _init_syncs(self) -> None:
+        self._syncs = Counter()
+        self._num_syncs = 0
+
+    def _count_sync(self, site: str, n: int = 1) -> None:
+        self._syncs[site] += n
+        self._num_syncs += n
+
+    def advance(self, k: int = 16) -> int:
+        """Run at most ``k`` supersteps; returns how many executed."""
+        with TraceAnnotation("stream.sync"):
+            self._count_sync("advance")
+            before = self._supersteps()
+        with TraceAnnotation("stream.dispatch"):
+            self._dispatch(k)
+        with TraceAnnotation("stream.sync"):
+            self._count_sync("advance")
+            return self._supersteps() - before
+
+    def done_mask(self) -> np.ndarray:
+        """(capacity,) bool — True where that slot's query terminated
+        (free slots included)."""
+        self._count_sync("done_mask")
+        return self._done_flags()
 
     # -- ring economy ------------------------------------------------------
 
@@ -556,7 +624,8 @@ class _StreamBase:
             raise ValueError("release with duplicate slot ids")
         if not self._live[qids].all():
             raise ValueError("release of a slot that is not live")
-        done = self.done_mask()
+        self._count_sync("release")
+        done = self._done_flags()
         if not done[qids].all():
             raise ValueError(
                 "release of an unfinished query: harvest only completed "
@@ -589,6 +658,7 @@ class _StreamBase:
         numpy (one recorded host round-trip over :meth:`harvest_device`)."""
         paths, lengths = self.harvest_device(qids)
         corpus_ring.record_host_copy("harvest_ids")
+        self._count_sync("harvest", 2)
         return np.asarray(paths), np.asarray(lengths)
 
     def done_live_mask(self) -> np.ndarray:
@@ -640,6 +710,7 @@ class WalkStream(_StreamBase):
             program.spec, self._cfg,
             cache=maybe_build_cache(program.spec, self._cfg, graph))
         self.state: StreamState = init_stream_state(self._cfg, self.capacity)
+        self._init_syncs()
         self._init_ring()
 
     @property
@@ -667,14 +738,13 @@ class WalkStream(_StreamBase):
         self.state = inject_queries(self.state, jnp.asarray(qb),
                                     jnp.asarray(sb), jnp.asarray(eb), n)
 
-    def advance(self, k: int = 16) -> int:
-        """Run at most ``k`` supersteps; returns how many executed."""
-        before = int(self.state.stats.supersteps)
+    def _dispatch(self, k: int) -> None:
         self.state = self._runner(self.graph, self.state, self.seed, k)
-        return int(self.state.stats.supersteps) - before
 
-    def done_mask(self) -> np.ndarray:
-        """(capacity,) bool — True where that slot's query terminated."""
+    def _supersteps(self) -> int:
+        return int(self.state.stats.supersteps)
+
+    def _done_flags(self) -> np.ndarray:
         return np.asarray(self.state.done)
 
     def harvest_device(self, qids):
@@ -684,6 +754,7 @@ class WalkStream(_StreamBase):
 
     def walk_stats(self) -> WalkStats:
         """Engine counters since construction/reset (host ints)."""
+        self._count_sync("walk_stats", len(WalkStats._fields))
         return WalkStats(*(int(getattr(self.state.stats, f))
                            for f in WalkStats._fields))
 
@@ -732,6 +803,7 @@ class ShardedWalkStream(_StreamBase):
                                             self.capacity)
         self._base_key = task_rng.stream_key(seed)
         self._next_dev = 0  # round-robin staging cursor
+        self._init_syncs()
         self._init_ring()
 
     @property
@@ -769,15 +841,14 @@ class ShardedWalkStream(_StreamBase):
             self.state, jnp.asarray(sb), jnp.asarray(qb), jnp.asarray(eb),
             jnp.asarray(cnt))
 
-    def advance(self, k: int = 16) -> int:
-        """Run at most ``k`` supersteps; returns how many executed."""
-        before = int(jnp.max(self.state.stats.supersteps))
+    def _dispatch(self, k: int) -> None:
         self.state = self._runner(self.graph, self.state, self._base_key, k)
-        return int(jnp.max(self.state.stats.supersteps)) - before
 
-    def done_mask(self) -> np.ndarray:
-        """(capacity,) bool — a slot is done once any device terminated
-        its occupant's walk."""
+    def _supersteps(self) -> int:
+        return int(jnp.max(self.state.stats.supersteps))
+
+    def _done_flags(self) -> np.ndarray:
+        # A slot is done once any device terminated its occupant's walk.
         return np.asarray(jnp.any(self.state.done, axis=0))
 
     def harvest_device(self, qids):
@@ -790,6 +861,7 @@ class ShardedWalkStream(_StreamBase):
     def walk_stats(self) -> WalkStats:
         """Engine counters summed across devices (supersteps/launches are
         the global lockstep clock: max)."""
+        self._count_sync("walk_stats", len(WalkStats._fields))
         return WalkStats(*(
             int(jnp.max(v)) if name in ("supersteps", "launches")
             else int(jnp.sum(v))

@@ -49,3 +49,89 @@ def test_default_directory_is_fixed_inside_checkout():
     ignored = (REPO / ".gitignore").read_text().split()
     if ".jax_cache/" not in ignored:
         pytest.fail(".jax_cache/ must be listed in .gitignore")
+
+
+_SCOPES = """
+import jax, jax.numpy as jnp
+from repro.compile_cache import use_compile_cache
+use_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def plain():
+    def run(x):
+        return jnp.sin(x) * 3 + 1
+    return run
+
+
+def scoped():
+    def run(x):
+        with jax.named_scope("walk.record"):
+            return jnp.sin(x) * 3 + 1
+    return run
+
+
+x = jnp.arange(7.0)
+for make in (plain, scoped):
+    text = jax.jit(make()).lower(x).compile().as_text()
+    print(make.__name__, "walk.record" in text)
+"""
+
+
+def test_a_build_with_named_scopes_gets_its_own_cache_entry(tmp_path):
+    """Two builds of one program that differ only in a named scope do not
+    share a cache entry: the scoped build, compiled second, keeps its own
+    op names (a device trace reads them) rather than loading the other's."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", _SCOPES], capture_output=True,
+                       text=True, env=env, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == ["plain", "False", "scoped", "True"]
+    assert len(list(tmp_path.iterdir())) >= 2
+
+
+_PROBE = """
+import jax, jax.numpy as jnp
+
+
+def run(x):
+    with jax.named_scope("walk.record"):
+        return jnp.sin(x) * 3 + 1
+"""
+
+_FROM_CHECKOUT = """
+import os, jax, jax.numpy as jnp
+from repro.compile_cache import use_compile_cache
+use_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.probe import run
+jax.jit(run)(jnp.arange(7.0)).block_until_ready()
+print(len(os.listdir(os.environ["JAX_COMPILATION_CACHE_DIR"])))
+"""
+
+
+def test_the_same_code_checked_out_elsewhere_finds_its_cache_entries(
+        tmp_path):
+    """The source paths in the metadata the cache key holds are relative to
+    the checkout: a second checkout of the same code at another path loads
+    the first one's entries and writes none of its own."""
+    cache = tmp_path / "cache"
+    counts = []
+    for name in ("one", "two"):
+        pkg = tmp_path / name / "src" / "repro"
+        pkg.mkdir(parents=True)
+        (pkg / "__init__.py").write_text("")
+        (pkg / "compile_cache.py").write_text(
+            (REPO / "src" / "repro" / "compile_cache.py").read_text())
+        (pkg / "probe.py").write_text(_PROBE)
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache),
+                   PYTHONPATH=str(pkg.parent), JAX_PLATFORMS="cpu")
+        r = subprocess.run([sys.executable, "-c", _FROM_CHECKOUT],
+                           capture_output=True, text=True, env=env,
+                           timeout=120)
+        assert r.returncode == 0, r.stderr
+        counts.append(int(r.stdout.split()[-1]))
+    assert counts[0] >= 1 and counts[1] == counts[0]

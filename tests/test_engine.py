@@ -154,3 +154,27 @@ def test_metapath_early_termination(rng):
     assert l.mean() < 16
     a = analyze_run(res.stats)
     assert a.starved == 0
+
+
+@pytest.mark.parametrize("algo", ["ppr", "deepwalk"])
+def test_superstep_phases_are_named_in_the_compiled_program(
+        algo, weighted_graph):
+    """The jnp superstep's ``jax.named_scope`` phases survive into the
+    compiled program's op metadata, where a device trace reads them; every
+    scatter into the 32 x 9 paths buffer is ``walk.record``."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.configs.ridgewalker import ALGORITHMS
+    from repro.core.walk_engine import build_engine
+    run = build_engine(ALGORITHMS[algo], EngineConfig(num_slots=16,
+                                                      max_hops=8))
+    hlo = run.lower(weighted_graph, jnp.zeros((32,), jnp.int32), 0,
+                    num_queries=32).compile().as_text().splitlines()
+    for scope in ("walk.sample", "walk.commit", "walk.refill",
+                  "walk.record"):
+        assert any(f"/{scope}/" in line for line in hlo), scope
+    paths = [line for line in hlo
+             if re.search(r"= s32\[32,9\]\S* scatter\(", line)]
+    assert paths and all("/walk.record/" in line for line in paths)

@@ -2,7 +2,10 @@
 mid-stream injection, multi-tenant harvesting, and the ring-buffer slot
 economy (continuous reclamation, epoch-salted RNG, no drain barrier)."""
 import dataclasses
+from collections import Counter
+from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +16,7 @@ from repro.core import EngineConfig, rng as task_rng
 from repro.core.samplers import SamplerSpec
 from repro.core.walk_engine import (init_stream_state, inject_queries,
                                     make_superstep_runner, run_walks)
+from repro.graph.csr import build_csr
 from repro.serve import OpenLoad, WalkService, run_open_load
 
 CFG = EngineConfig(num_slots=64, max_hops=12)
@@ -328,3 +332,110 @@ def test_soak_sharded_ring_two_devices(tmp_path):
     out = run_in_subprocess(SHARDED_SOAK, devices=2,
                             extra_path=os.fspath(tmp_path))
     assert "SHARDED_SOAK_OK" in out
+
+
+# ------------------------------------------------- spans and sync counters
+
+
+def _cycle_graph(n: int = 16):
+    """A ring with edges both ways: no walk dead-ends, so a walk of ``h``
+    hops ends in exactly its ``h + 1``-th superstep."""
+    edges = [(i, (i + 1) % n) for i in range(n)]
+    return build_csr(np.array(edges + [(b, a) for a, b in edges]), n)
+
+
+@pytest.mark.parametrize("backend", ["single", "sharded"])
+def test_service_step_sync_counts(backend):
+    """Blocking device→host reads of one ``WalkService.step``, by site: two
+    reads of the superstep clock around the chunk and one ``done_mask``,
+    then per harvested request two (paths, lengths) and one (the release
+    check).  Pinned: a change that removes a sync changes these on
+    purpose."""
+    g = _cycle_graph()
+    w = walker.compile(walker.WalkProgram.urw(4), backend=backend,
+                       execution=walker.ExecutionConfig(num_slots=8))
+    svc = w.serve(g, capacity=16, chunk=64, seed=1)
+
+    def one_step(requests, k=None):
+        before = svc.counters
+        for n in requests:
+            svc.submit(np.arange(n, dtype=np.int32))
+        svc.step(k)
+        return svc.counters - before
+
+    chunk = Counter({"chunks": 1, "sync.advance": 2, "sync.done_mask": 1})
+    assert one_step([], None) == Counter()          # nothing to run
+    assert one_step([4], 2) == chunk                # 4 hops, 2 supersteps
+    assert svc.num_inflight == 1
+    assert one_step([]) == chunk + Counter({"sync.harvest": 2,
+                                            "sync.release": 1})
+    assert one_step([3, 3]) == chunk + Counter({"sync.harvest": 4,
+                                                "sync.release": 2})
+    assert svc.num_inflight == svc.num_pending == 0
+    # Reading the engine counters is a blocking read of each of them.
+    before = svc.counters
+    svc.walk_stats()
+    assert svc.counters - before == Counter(
+        {"sync.walk_stats": len(svc.walk_stats()._fields)})
+    assert svc.stream.num_syncs == sum(svc.stream.syncs.values())
+
+
+def _program_spans(log_dir) -> list:
+    """``(name, metadata)`` of the program's spans in a profiler trace."""
+    path = next(Path(log_dir).glob("**/*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    name = ev.name.split("#", 1)[0]
+                    if name.startswith(("serve.", "stream.", "walker.")):
+                        out.append((name, dict(ev.stats)))
+    return out
+
+
+def test_service_and_walker_spans_in_a_profiler_trace(tmp_path):
+    """The service's and ``Walker.run``'s spans land in a ``jax.profiler``
+    trace with their metadata: request ids on ``serve.harvest``, and the
+    step's chunks and syncs on ``serve.step``."""
+    g = _cycle_graph()
+    ex = walker.ExecutionConfig(num_slots=8)
+    single = walker.compile(walker.WalkProgram.urw(4), execution=ex)
+    sharded = walker.compile(walker.WalkProgram.urw(4), backend="sharded",
+                             execution=ex)
+    svc = single.serve(g, capacity=16, chunk=2, seed=1)
+    starts = np.arange(4, dtype=np.int32)
+    for w in (single, sharded):             # compile outside the trace
+        w.run(g, starts)
+    svc.submit(starts)
+    svc.drain()
+    before = svc.counters
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        rids = [svc.submit(starts[:3]) for _ in range(2)]
+        svc.drain()
+        for w in (single, sharded):
+            w.run(g, starts, seed=2)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _program_spans(tmp_path)
+    names = Counter(n for n, _ in spans)
+    assert set(names) == {
+        "serve.step", "serve.admit", "serve.advance", "stream.dispatch",
+        "stream.sync", "serve.done_mask", "serve.harvest", "serve.release",
+        "walker.bind", "walker.engine", "walker.assemble"}
+    assert names["walker.bind"] == names["walker.engine"] == 2
+    assert names["walker.assemble"] == 1    # sharded only
+    assert names["stream.sync"] == 2 * names["stream.dispatch"]
+    assert names["serve.release"] == names["serve.harvest"] == 2
+    assert sorted(m["request_id"] for n, m in spans
+                  if n == "serve.harvest") == rids
+    steps = [m for n, m in spans if n == "serve.step"]
+    delta = svc.counters - before
+    assert sum(m["chunks"] for m in steps) == delta["chunks"] > 0
+    assert sum(m["syncs"] for m in steps) == sum(
+        v for k, v in delta.items() if k.startswith("sync."))
+    assert sum(m["admitted"] for n, m in spans if n == "serve.admit") == 2
