@@ -22,10 +22,15 @@ The chunk size is the host-injection granularity: smaller chunks admit
 arrivals sooner (lower sojourn) at the cost of more host↔device syncs —
 the open-system analogue of the paper's §VI-A injection delay C.
 
+Every request that finishes in a step is harvested together: one jitted
+gather of all their rows and one blocking read, after which the rows are
+split back per request and all their slots released.
+
 Each phase is a ``jax.profiler`` span (``serve.step`` around ``serve.admit``,
-``serve.advance``, ``serve.done_mask`` and one ``serve.harvest`` per
-finished request with its ``serve.release``), and :attr:`WalkService.counters`
-counts the chunks run and the stream's blocking device→host reads by site,
+``serve.advance``, ``serve.done_mask`` and, in a step that finishes
+requests, one ``serve.harvest`` with its ``serve.release``), and
+:attr:`WalkService.counters` counts the chunks run, the harvests and the
+requests they took, and the stream's blocking device→host reads by site,
 so a trace or a window of counters says where a step's host time goes
 (``docs/api.md``, "Tracing a running service").
 
@@ -167,6 +172,8 @@ class WalkService:
         self._completed: Dict[int, WalkRequest] = {}
         self._next_rid = 0
         self._chunks = 0          # steps that ran a chunk
+        self._harvests = 0        # steps that harvested requests
+        self._harvested = 0       # requests they harvested
 
     # ------------------------------------------------------------ geometry
 
@@ -282,20 +289,31 @@ class WalkService:
     def _harvest(self) -> None:
         with TraceAnnotation("serve.done_mask"):
             done = self.stream.done_mask()
-        finished: List[WalkRequest] = []
-        for req in self._inflight.values():
-            if done[req.qids].all():
-                finished.append(req)
-        for req in finished:
-            with TraceAnnotation("serve.harvest", request_id=req.request_id):
-                req.paths, req.lengths = self.stream.harvest_ids(req.qids)
+        finished = [req for req in self._inflight.values()
+                    if done[req.qids].all()]
+        if not finished:
+            return
+        ids = " ".join(str(req.request_id) for req in finished)
+        with TraceAnnotation("serve.harvest", requests=len(finished),
+                             request_ids=ids):
+            qids = np.concatenate([req.qids for req in finished])
+            paths, lengths = self.stream.harvest_ids(qids)
+            now = time.perf_counter()
+            lo = 0
+            for req in finished:
+                hi = lo + req.num_walks
+                req.paths, req.lengths = paths[lo:hi], lengths[lo:hi]
                 req.completed_at = self.clock
-                req.wall_completed = time.perf_counter()
-                with TraceAnnotation("serve.release"):
-                    # slots go around again (epoch+1)
-                    self.stream.release(req.qids)
+                req.wall_completed = now
+                lo = hi
+            with TraceAnnotation("serve.release"):
+                # slots go around again (epoch+1), request by request
+                self.stream.release(qids)
+        for req in finished:
             del self._inflight[req.request_id]
             self._completed[req.request_id] = req
+        self._harvests += 1
+        self._harvested += len(finished)
 
     def drain(self) -> List[WalkRequest]:
         """Run until every submitted request has completed."""
@@ -358,12 +376,16 @@ class WalkService:
     def counters(self) -> Counter:
         """Host-side counts since construction (a copy; ``reset_metrics``
         keeps them), to be differenced over a window as :meth:`walk_stats`
-        is: ``chunks``, the steps that ran a chunk, and ``sync.<site>``,
-        the stream's blocking device→host reads by site (``advance``,
-        ``done_mask``, ``harvest``, ``release``, ``walk_stats``)."""
+        is: ``chunks``, the steps that ran a chunk; ``harvests``, the steps
+        that harvested finished requests, and ``harvested``, those
+        requests; and ``sync.<site>``, the stream's blocking device→host
+        reads by site (``advance``, ``done_mask``, ``harvest``,
+        ``release``, ``walk_stats``)."""
         out = Counter({f"sync.{site}": n
                        for site, n in self.stream.syncs.items()})
         out["chunks"] = self._chunks
+        out["harvests"] = self._harvests
+        out["harvested"] = self._harvested
         return out
 
     def sojourns(self) -> List[int]:

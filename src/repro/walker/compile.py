@@ -60,6 +60,29 @@ def _pad_block(n: int, floor: int = 16) -> int:
     return b
 
 
+def _harvest_blocks(capacity: int) -> list:
+    """Every block size a harvest of 1..capacity rows pads to."""
+    blocks, b = [], _pad_block(1)
+    while b < capacity:
+        blocks.append(b)
+        b <<= 1
+    return blocks + [capacity]
+
+
+@jax.jit
+def _gather_rows(paths, lengths, idx):
+    """Rows ``idx`` of a single-device stream's path and length buffers."""
+    return paths[idx], lengths[idx]
+
+
+@jax.jit
+def _gather_rows_sharded(paths, lengths, idx):
+    """Rows ``idx`` of a sharded stream's buffers, max-folded over the
+    device axis (each hop is recorded by exactly the device that ran it)."""
+    return (jnp.max(paths[:, idx, :], axis=0),
+            jnp.max(lengths[:, idx], axis=0))
+
+
 def compile(program: WalkProgram, backend: str = "single",
             execution: Optional[ExecutionConfig] = None,
             mesh: Optional[jax.sharding.Mesh] = None) -> "Walker":
@@ -495,6 +518,20 @@ class _StreamBase:
     """
 
     capacity: int
+    #: The backend's jitted row gather, ``(paths, lengths, idx)`` → rows.
+    _rows = None
+    _rows_warm = False
+
+    @property
+    def state(self):
+        """The device state.  Replacing it (a dispatch, an injection, a
+        reset) voids the last :meth:`done_mask` read."""
+        return self._state
+
+    @state.setter
+    def state(self, state) -> None:
+        self._state = state
+        self._done_read = None
 
     def _init_ring(self) -> None:
         self._free = deque(range(self.capacity))
@@ -520,10 +557,30 @@ class _StreamBase:
         """Per-slot completion flags (one blocking read)."""
         raise NotImplementedError
 
+    def _gather(self, qids):
+        """``(paths, lengths)`` rows of ``qids`` on device, padded to a
+        power-of-two block by one call of the backend's jitted gather, and
+        the number of real rows.  The first call compiles every block size
+        a harvest can present, so none compiles later."""
+        idx = np.asarray(qids, np.int32).reshape(-1)
+        n = idx.size
+        if not self._rows_warm:
+            for b in _harvest_blocks(self.capacity):
+                self._rows(self.state.paths, self.state.lengths,
+                           np.zeros((b,), np.int32))
+            self._rows_warm = True
+        # Pad rows gather slot 0 and are dropped.
+        block = np.zeros((min(_pad_block(n), self.capacity),), np.int32)
+        block[:n] = idx
+        return self._rows(self.state.paths, self.state.lengths, block), n
+
     def harvest_device(self, qids):
         """Fetch ``(paths, lengths)`` for the given live query-id slots as
         *device-resident* arrays (no host copy) — the corpus-ring feed."""
-        raise NotImplementedError
+        (paths, lengths), n = self._gather(qids)
+        if n == paths.shape[0]:
+            return paths, lengths
+        return paths[:n], lengths[:n]
 
     # -- device reads --------------------------------------------------------
 
@@ -531,8 +588,9 @@ class _StreamBase:
     def syncs(self) -> Counter:
         """Blocking device→host reads since construction, by site (a copy):
         ``advance`` (the superstep clock before and after a chunk),
-        ``done_mask``, ``harvest`` (paths and lengths of
-        :meth:`harvest_ids`), ``release`` (its ``done`` check) and
+        ``done_mask``, ``harvest`` (one read of the paths and lengths of
+        :meth:`harvest_ids`), ``release`` (its ``done`` check, where no
+        :meth:`done_mask` read of the current state can serve) and
         ``walk_stats`` (one per counter)."""
         return Counter(self._syncs)
 
@@ -564,7 +622,8 @@ class _StreamBase:
         """(capacity,) bool — True where that slot's query terminated
         (free slots included)."""
         self._count_sync("done_mask")
-        return self._done_flags()
+        self._done_read = self._done_flags()
+        return self._done_read
 
     # -- ring economy ------------------------------------------------------
 
@@ -616,7 +675,11 @@ class _StreamBase:
         return qids, epochs
 
     def release(self, qids) -> None:
-        """Return harvested slots to the free ring with ``epoch + 1``."""
+        """Return harvested slots to the free ring with ``epoch + 1``.
+
+        Every slot must read ``done``: in the last :meth:`done_mask` read
+        where nothing has replaced the device state since, else in a new
+        read."""
         qids = np.asarray(qids, np.int64).reshape(-1)
         if np.unique(qids).size != qids.size:
             # A duplicate would enter the free ring twice and hand the same
@@ -624,8 +687,10 @@ class _StreamBase:
             raise ValueError("release with duplicate slot ids")
         if not self._live[qids].all():
             raise ValueError("release of a slot that is not live")
-        self._count_sync("release")
-        done = self._done_flags()
+        done = self._done_read
+        if done is None:    # the state changed since the last done_mask
+            self._count_sync("release")
+            done = self._done_read = self._done_flags()
         if not done[qids].all():
             raise ValueError(
                 "release of an unfinished query: harvest only completed "
@@ -655,11 +720,12 @@ class _StreamBase:
 
     def harvest_ids(self, qids):
         """Fetch ``(paths, lengths)`` for the given live query-id slots as
-        numpy (one recorded host round-trip over :meth:`harvest_device`)."""
-        paths, lengths = self.harvest_device(qids)
+        numpy: one jitted gather and one recorded host round-trip of both."""
+        (paths, lengths), n = self._gather(qids)
         corpus_ring.record_host_copy("harvest_ids")
-        self._count_sync("harvest", 2)
-        return np.asarray(paths), np.asarray(lengths)
+        self._count_sync("harvest")
+        paths, lengths = jax.device_get((paths, lengths))
+        return paths[:n], lengths[:n]
 
     def done_live_mask(self) -> np.ndarray:
         """(capacity,) bool — live slots whose query has terminated (the
@@ -692,6 +758,8 @@ class WalkStream(_StreamBase):
     state lives in a :class:`~repro.core.StreamState` whose shapes are
     static, so any injection/advance cadence reuses one compilation.
     """
+
+    _rows = staticmethod(_gather_rows)
 
     def __init__(self, program: WalkProgram, execution: ExecutionConfig,
                  graph, capacity: int, seed: int):
@@ -747,11 +815,6 @@ class WalkStream(_StreamBase):
     def _done_flags(self) -> np.ndarray:
         return np.asarray(self.state.done)
 
-    def harvest_device(self, qids):
-        """Recorded (paths, lengths) rows for the given slot ids (device)."""
-        idx = jnp.asarray(np.asarray(qids, np.int32))
-        return self.state.paths[idx], self.state.lengths[idx]
-
     def walk_stats(self) -> WalkStats:
         """Engine counters since construction/reset (host ints)."""
         self._count_sync("walk_stats", len(WalkStats._fields))
@@ -785,6 +848,8 @@ class ShardedWalkStream(_StreamBase):
     ``Walker.run`` samples for query ``qid`` under
     ``rng.stream_key(seed, epoch)`` — identical across backends.
     """
+
+    _rows = staticmethod(_gather_rows_sharded)
 
     def __init__(self, program: WalkProgram, cfg, pg: PartitionedGraph,
                  mesh, capacity: int, seed: int):
@@ -850,13 +915,6 @@ class ShardedWalkStream(_StreamBase):
     def _done_flags(self) -> np.ndarray:
         # A slot is done once any device terminated its occupant's walk.
         return np.asarray(jnp.any(self.state.done, axis=0))
-
-    def harvest_device(self, qids):
-        """Max-fold the per-device path windows for the given slot ids —
-        a cross-device reduction, but the result stays on device."""
-        idx = jnp.asarray(np.asarray(qids, np.int32))
-        return (jnp.max(self.state.paths[:, idx, :], axis=0),
-                jnp.max(self.state.lengths[:, idx], axis=0))
 
     def walk_stats(self) -> WalkStats:
         """Engine counters summed across devices (supersteps/launches are

@@ -348,9 +348,9 @@ def _cycle_graph(n: int = 16):
 def test_service_step_sync_counts(backend):
     """Blocking device→host reads of one ``WalkService.step``, by site: two
     reads of the superstep clock around the chunk and one ``done_mask``,
-    then per harvested request two (paths, lengths) and one (the release
-    check).  Pinned: a change that removes a sync changes these on
-    purpose."""
+    then, in a step that finishes requests, one read of all their paths
+    and lengths; the release check reuses the step's ``done_mask`` read.
+    Pinned: a change that removes a sync changes these on purpose."""
     g = _cycle_graph()
     w = walker.compile(walker.WalkProgram.urw(4), backend=backend,
                        execution=walker.ExecutionConfig(num_slots=8))
@@ -367,10 +367,11 @@ def test_service_step_sync_counts(backend):
     assert one_step([], None) == Counter()          # nothing to run
     assert one_step([4], 2) == chunk                # 4 hops, 2 supersteps
     assert svc.num_inflight == 1
-    assert one_step([]) == chunk + Counter({"sync.harvest": 2,
-                                            "sync.release": 1})
-    assert one_step([3, 3]) == chunk + Counter({"sync.harvest": 4,
-                                                "sync.release": 2})
+    assert one_step([]) == chunk + Counter({"sync.harvest": 1,
+                                            "harvests": 1, "harvested": 1})
+    assert one_step([3, 3]) == chunk + Counter({"sync.harvest": 1,
+                                                "harvests": 1,
+                                                "harvested": 2})
     assert svc.num_inflight == svc.num_pending == 0
     # Reading the engine counters is a blocking read of each of them.
     before = svc.counters
@@ -396,8 +397,9 @@ def _program_spans(log_dir) -> list:
 
 def test_service_and_walker_spans_in_a_profiler_trace(tmp_path):
     """The service's and ``Walker.run``'s spans land in a ``jax.profiler``
-    trace with their metadata: request ids on ``serve.harvest``, and the
-    step's chunks and syncs on ``serve.step``."""
+    trace with their metadata: one ``serve.harvest`` per harvesting step
+    with its request count and ids, and the step's chunks and syncs on
+    ``serve.step``."""
     g = _cycle_graph()
     ex = walker.ExecutionConfig(num_slots=8)
     single = walker.compile(walker.WalkProgram.urw(4), execution=ex)
@@ -430,12 +432,109 @@ def test_service_and_walker_spans_in_a_profiler_trace(tmp_path):
     assert names["walker.bind"] == names["walker.engine"] == 2
     assert names["walker.assemble"] == 1    # sharded only
     assert names["stream.sync"] == 2 * names["stream.dispatch"]
-    assert names["serve.release"] == names["serve.harvest"] == 2
-    assert sorted(m["request_id"] for n, m in spans
-                  if n == "serve.harvest") == rids
+    # Both requests finish in one step: one harvest takes them together.
+    assert names["serve.release"] == names["serve.harvest"] == 1
+    harvests = [m for n, m in spans if n == "serve.harvest"]
+    assert [m["requests"] for m in harvests] == [2]
+    assert sorted(int(r) for m in harvests
+                  for r in str(m["request_ids"]).split()) == rids
     steps = [m for n, m in spans if n == "serve.step"]
     delta = svc.counters - before
+    assert delta["harvests"] == 1 and delta["harvested"] == 2
     assert sum(m["chunks"] for m in steps) == delta["chunks"] > 0
     assert sum(m["syncs"] for m in steps) == sum(
         v for k, v in delta.items() if k.startswith("sync."))
     assert sum(m["admitted"] for n, m in spans if n == "serve.admit") == 2
+
+
+# ------------------------------------------------------- batched harvest
+
+
+def _urw_walker(backend):
+    return walker.compile(walker.WalkProgram.urw(4), backend=backend,
+                          execution=walker.ExecutionConfig(num_slots=8))
+
+
+@pytest.mark.parametrize("backend", ["single", "sharded"])
+@pytest.mark.parametrize("sizes", [[7], [5, 17], [3, 20, 40]],
+                         ids=["one", "two", "three"])
+def test_requests_harvested_together_match_each_alone(backend, sizes,
+                                                      small_graph, rng):
+    """Requests that finish in the same step, harvested by one gather and
+    one read, get exactly the paths and lengths that harvesting each
+    request alone gives, and leave the same epochs and free ring; over two
+    rounds, so the second runs on recycled slots."""
+    w = _urw_walker(backend)
+    cap = sum(sizes) + 1            # the second round wraps the ring
+    svc = w.serve(small_graph, capacity=cap, chunk=64, seed=4)
+    alone = w.stream(small_graph, capacity=cap, seed=4)
+    for round_ in range(2):
+        starts = [rng.integers(0, small_graph.num_vertices, n)
+                  for n in sizes]
+        before = svc.counters
+        rids = [svc.submit(s) for s in starts]
+        svc.step()          # every walk ends within 5 supersteps
+        assert svc.counters - before >= Counter(
+            {"harvests": 1, "harvested": len(sizes)})
+        for rid, s in zip(rids, starts):
+            req = svc.poll(rid)
+            qids, epochs = alone.inject(s)
+            np.testing.assert_array_equal(qids, req.qids)
+            np.testing.assert_array_equal(epochs, req.epochs)
+            alone.drain()
+            paths, lengths = alone.harvest_ids(qids)
+            alone.release(qids)
+            np.testing.assert_array_equal(req.paths, paths)
+            np.testing.assert_array_equal(req.lengths, lengths)
+        every = np.arange(cap)
+        np.testing.assert_array_equal(svc.stream.epoch_of(every),
+                                      alone.epoch_of(every))
+        assert list(svc.stream._free) == list(alone._free)
+        assert max(int(svc.poll(r).epochs.max()) for r in rids) == round_
+
+
+@pytest.mark.parametrize("backend", ["single", "sharded"])
+def test_release_reuses_the_done_mask_read(backend):
+    """``release`` right after ``done_mask`` makes no blocking read; once
+    a dispatch has replaced the state it reads ``done`` again, and still
+    refuses a slot whose walk has not ended."""
+    stream = _urw_walker(backend).stream(_cycle_graph(), capacity=16, seed=1)
+    qids, _ = stream.inject(np.arange(4))
+    stream.drain()                  # ends on a done_mask read
+    before = stream.syncs
+    stream.release(qids)
+    assert stream.syncs == before
+    qids, _ = stream.inject(np.arange(4))
+    assert not stream.done_mask()[qids].any()
+    with pytest.raises(ValueError, match="unfinished"):
+        stream.release(qids)        # refused on the done_mask read
+    assert stream.syncs - before == Counter({"done_mask": 1})
+    stream.advance(1)               # 4 hops take 5 supersteps
+    with pytest.raises(ValueError, match="unfinished"):
+        stream.release(qids)
+    assert stream.syncs["release"] == before["release"] + 1
+
+
+@pytest.mark.parametrize("backend", ["single", "sharded"])
+def test_harvest_compiles_no_shape_after_warm_up(backend):
+    """Once a service has harvested its first request, requests finishing
+    in any grouping, up to the whole ring, make the jitted gather compile
+    no further shape."""
+    svc = _urw_walker(backend).serve(_cycle_graph(), capacity=64, chunk=64,
+                                     seed=2)
+    svc.submit(np.arange(2, dtype=np.int32))
+    svc.drain()
+    gather = type(svc.stream)._rows
+    compiled = gather._cache_size()
+    before = svc.counters
+    groupings = [[1], [2, 3], [10, 10], [16, 17], [30, 30, 4], [64],
+                 [9] * 7]
+    for sizes in groupings:
+        for n in sizes:
+            svc.submit(np.arange(n, dtype=np.int32) % 16)
+        svc.step()
+        assert svc.num_inflight == 0
+    assert gather._cache_size() == compiled
+    delta = svc.counters - before
+    assert delta["harvests"] == len(groupings)
+    assert delta["harvested"] == sum(len(g) for g in groupings)
